@@ -594,12 +594,12 @@ for shard in ("never", "always"):
 def sweep_sharded(ndev: int = 4) -> List[str]:
     """The 28x4 sweep grid with the lane axis sharded over ``ndev`` forced
     host devices vs the single-device path, in a subprocess (device count is
-    fixed at jax init).  On one physical CPU the shards share cores, so the
-    derived speedup ratio is the honest lower bound; on a real multi-chip
-    host each shard gets its own chip."""
+    fixed at jax init).  A host-device emulation row: the child runs with
+    ``JAX_PLATFORMS=cpu``, so it never contends with this process for a
+    chip, and on one physical CPU the shards share cores."""
     import subprocess
     import sys
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS=f"--xla_force_host_platform_device_count={ndev}")
     src = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
                                        "src"))

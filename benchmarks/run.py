@@ -76,6 +76,8 @@ def main(argv=None) -> None:
         os.environ.setdefault("BENCH_REPEATS", "1")
 
     from repro import obs
+    from repro.compile_cache import enable
+    enable()
     if args.json:
         # record spans for the run log riding next to the JSON artifact
         obs.reset(counters_too=False)

@@ -145,6 +145,8 @@ def main(argv: Optional[List[str]] = None) -> None:
     ap.add_argument("command",
                     choices=["sweep", "serve", "bench", "obs", "validate"])
     args, rest = ap.parse_known_args(argv)
+    from .compile_cache import enable
+    enable()
     if args.command == "sweep":
         from .sweep.__main__ import main as sweep_main
         sweep_main(rest)

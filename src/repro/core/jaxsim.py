@@ -74,8 +74,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..kernels.fitscore import (ARRIVAL_KIND, DEPARTURE_KIND, F32_EPS, IBIG,
-                                KCAT, LOC_B, LOC_C, LOC_G, LOC_L,
+from ..kernels.fitscore import (ARRIVAL_KIND, DEPARTURE_KIND, F32_EPS,
+                                FIT_CAP, IBIG, KCAT, LOC_B, LOC_C, LOC_G,
+                                LOC_L,
                                 MIGRATE_KIND, PAD_KIND,
                                 SCORE_BIG, SCORE_NEG, SELECT_POLICIES,
                                 TAG_BASE, TAG_GENERAL, TAG_LARGE, TAG_NONE,
@@ -291,7 +292,7 @@ def _score(policy, loads, alive, open_seq, access_seq, closes, size,
     common d; zero-size padded dims never affect feasibility but must be
     excluded from the best-fit residual norms.  ``cmask`` (n_slots,)
     restricts feasibility to category-compatible slots (None = all)."""
-    feasible = jnp.all(size[None, :] <= 1.0 - loads + F32_EPS, axis=1) & alive
+    feasible = jnp.all(size[None, :] <= FIT_CAP - loads, axis=1) & alive
     if cmask is not None:
         feasible = feasible & cmask
     if policy == "first_fit":
@@ -956,7 +957,7 @@ def _replay_batch(sizes, times, kinds, items, pdeps, dmask, arrivals=None,
                 base_loads = loads_s[lanes, jnp.maximum(cat_s["base"], 0)]
                 base_fits = jnp.where(
                     has_base,
-                    jnp.all(size <= 1.0 - base_loads + F32_EPS, axis=1),
+                    jnp.all(size <= FIT_CAP - base_loads, axis=1),
                     True)
                 if excl is not None:
                     # migrate off the base bin itself: the re-place must

@@ -48,11 +48,11 @@ VMEM tiling.  Three kernels live here:
     space is (lanes, event-blocks).  The serving scheduler reuses the same
     kernel at T=1 (``kernels.ops.fitscore_select_block``).
 
-Constants ``SCORE_BIG`` / ``SCORE_NEG`` / ``F32_EPS`` / ``IBIG`` /
-``SELECT_POLICIES`` plus the replay encodings (event kinds, TAG_* / LOC_*
-carry tags, KCAT) are the single source of truth for the scoring and
-replay semantics; ``core.jaxsim`` and ``kernels.ops`` import them so the
-inline jnp paths and the kernels can never drift.
+Constants ``SCORE_BIG`` / ``SCORE_NEG`` / ``F32_EPS`` / ``FIT_CAP`` /
+``IBIG`` / ``SELECT_POLICIES`` plus the replay encodings (event kinds,
+TAG_* / LOC_* carry tags, KCAT) are the single source of truth for the
+scoring and replay semantics; ``core.jaxsim`` and ``kernels.ops`` import
+them so the inline jnp paths and the kernels can never drift.
 """
 from __future__ import annotations
 
@@ -60,6 +60,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -74,6 +75,12 @@ SELECT_POLICIES = ("first_fit", "best_fit_l1", "best_fit_l2", "best_fit_linf",
 SCORE_BIG = 1e30     # +BIG == infeasible slot
 SCORE_NEG = -1e30    # closes sentinel for virgin/closed slots
 F32_EPS = 1e-6       # fp32 capacity tolerance (oracle uses 1e-9/f64)
+# An item fits a slot when size <= FIT_CAP - load in every dimension: the
+# unit capacity plus F32_EPS as one f32 constant, so the test is a single
+# subtraction.  XLA's TPU compiler folds ``1.0 - load + F32_EPS`` into this
+# form and Mosaic does not; written unfolded, the jnp twin and the kernels
+# split on near-full slots.
+FIT_CAP = float(np.float32(1.0) + np.float32(F32_EPS))
 IBIG = 2 ** 30      # int sentinel for (open_seq, row) tie-break argmins
 
 # --- shared replay semantics (single definition site; core.jaxsim
@@ -207,6 +214,11 @@ def _select_kernel(loads_ref, counts_ref, alive_ref, oseq_ref, aseq_ref,
                    nb: int, n: int):
     """One (lane, bin-tile) grid step of the fused placement decision.
 
+    Per-slot operands arrive as (1, 1, bn) blocks of (L, 1, Np) arrays and
+    size/dmask as (1, 1, dpad) blocks, so every block's last two dims are
+    (8, 128)-aligned or equal the array's - the TPU tiling rule at L > 1.
+    ``pdep_ref`` / ``now_ref`` are whole (L,) SMEM vectors.
+
     ``cmask_ref`` (1, bn) int32 is the *category mask*: 1 marks slots the
     policy's category structure allows for this arrival (same-tag bins for
     CBD/CBDT/Hybrid/RCP lanes, same-lifetime-class bins for Lifetime
@@ -240,25 +252,25 @@ def _select_kernel(loads_ref, counts_ref, alive_ref, oseq_ref, aseq_ref,
         ibest[4] = jnp.int32(IBIG)
 
     loads = loads_ref[...].astype(jnp.float32)    # (1, bn, dpad)
-    size = size_ref[...].astype(jnp.float32)      # (1, dpad)
-    dmask = dmask_ref[...].astype(jnp.float32)    # (1, dpad)
-    counts = counts_ref[...]                      # (1, bn) int32
-    oseq = oseq_ref[...]                          # (1, bn) int32
+    size = size_ref[0].astype(jnp.float32)        # (1, dpad)
+    dmask = dmask_ref[0].astype(jnp.float32)      # (1, dpad)
+    counts = counts_ref[0]                        # (1, bn) int32
+    oseq = oseq_ref[0]                            # (1, bn) int32
     rows = i * bn + jax.lax.broadcasted_iota(jnp.int32, (1, bn), 1)
     rowmask = rows < n
-    alive = (alive_ref[...] > 0) & rowmask
-    pdep = pdep_ref[0, 0]
-    now = now_ref[0, 0]
+    alive = (alive_ref[0] > 0) & rowmask
+    pdep = pdep_ref[b]
+    now = now_ref[b]
 
     # feasibility - the exact jnp expression of core.jaxsim._score,
     # restricted to category-compatible slots
-    feasible = jnp.all(size[:, None, :] <= 1.0 - loads + F32_EPS,
-                       axis=2) & alive & (cmask_ref[...] > 0)   # (1, bn)
+    feasible = jnp.all(size[:, None, :] <= FIT_CAP - loads,
+                       axis=2) & alive & (cmask_ref[0] > 0)     # (1, bn)
 
     if policy == "first_fit":
         s = oseq.astype(jnp.float32)
     elif policy == "mru":
-        s = -aseq_ref[...].astype(jnp.float32)
+        s = -aseq_ref[0].astype(jnp.float32)
     elif policy.startswith("best_fit"):
         after = 1.0 - loads - size[:, None, :]    # (1, bn, dpad)
         if policy.endswith("l1"):
@@ -270,11 +282,11 @@ def _select_kernel(loads_ref, counts_ref, alive_ref, oseq_ref, aseq_ref,
             s = jnp.max(jnp.where(dmask[:, None, :] > 0, after, SCORE_NEG),
                         axis=2)
     elif policy == "greedy":
-        s = -jnp.maximum(closes_ref[...], now)
+        s = -jnp.maximum(closes_ref[0], now)
     elif policy == "nrt_standard":
-        s = jnp.abs(jnp.maximum(closes_ref[...], now) - pdep)
+        s = jnp.abs(jnp.maximum(closes_ref[0], now) - pdep)
     else:   # nrt_prioritized
-        gap = jnp.maximum(closes_ref[...], now) - pdep
+        gap = jnp.maximum(closes_ref[0], now) - pdep
         sa = jnp.where(feasible & (gap >= 0), gap, SCORE_BIG)
         sb = jnp.where(feasible & (gap < 0), -gap, SCORE_BIG)
 
@@ -353,33 +365,27 @@ def fitscore_select_batch_padded(loads, counts, alive, open_seq, access_seq,
         cmask = jnp.ones((L, Np), i32)
     kernel = functools.partial(_select_kernel, policy=policy, bn=bn_, nb=nb,
                                n=n)
+    slot = pl.BlockSpec((1, 1, bn_), lambda b, i: (b, 0, i))
+    vec = pl.BlockSpec((1, 1, dpad), lambda b, i: (b, 0, 0))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+    def row(a, dt):   # (L, X) -> (L, 1, X): unit middle axis for tiling
+        return a.astype(dt).reshape(L, 1, a.shape[-1])
+
     out = pl.pallas_call(
         kernel,
         grid=(L, nb),
-        in_specs=[
-            pl.BlockSpec((1, bn_, dpad), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, bn_), lambda b, i: (b, i)),
-            pl.BlockSpec((1, bn_), lambda b, i: (b, i)),
-            pl.BlockSpec((1, bn_), lambda b, i: (b, i)),
-            pl.BlockSpec((1, bn_), lambda b, i: (b, i)),
-            pl.BlockSpec((1, bn_), lambda b, i: (b, i)),
-            pl.BlockSpec((1, dpad), lambda b, i: (b, 0)),
-            pl.BlockSpec((1, dpad), lambda b, i: (b, 0)),
-            pl.BlockSpec((1, bn_), lambda b, i: (b, i)),
-            pl.BlockSpec((1, 1), lambda b, i: (b, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda b, i: (b, 0),
-                         memory_space=pltpu.SMEM),
-        ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
+        in_specs=[pl.BlockSpec((1, bn_, dpad), lambda b, i: (b, i, 0)),
+                  slot, slot, slot, slot, slot, vec, vec, slot, smem, smem],
+        out_specs=smem,
         out_shape=jax.ShapeDtypeStruct((L, 3), jnp.int32),
         scratch_shapes=[pltpu.SMEM((2,), jnp.float32),
                         pltpu.SMEM((8,), jnp.int32)],
         interpret=interpret,
-    )(loads.astype(f32), counts.astype(i32), alive.astype(i32),
-      open_seq.astype(i32), access_seq.astype(i32), closes.astype(f32),
-      size.astype(f32), dmask.astype(f32), cmask.astype(i32),
-      pdep.astype(f32).reshape(L, 1), now.astype(f32).reshape(L, 1))
+    )(loads.astype(f32), row(counts, i32), row(alive, i32),
+      row(open_seq, i32), row(access_seq, i32), row(closes, f32),
+      row(size, f32), row(dmask, f32), row(cmask, i32),
+      pdep.astype(f32).reshape(L), now.astype(f32).reshape(L))
     return out[:, 0], out[:, 1] > 0, out[:, 2] > 0
 
 
@@ -410,9 +416,14 @@ def fitscore_select_batch_padded(loads, counts, alive, open_seq, access_seq,
 #                              base row at RAGG_BASE] (rcp only)
 #   ron    (L, KCAT, 8)  i32   rcp per-category ON flags (rcp only)
 #
-# Per-event inputs stream in as (L, T) SMEM scalar blocks plus one
+# Per-event inputs stream in as (L, T) SMEM scalar streams plus one
 # (L, T, dpad) VMEM block of pre-gathered item sizes - all pure functions
 # of the (predicted) durations, precomputed before the scan.
+#
+# TPU tiling: a block's last two dims must be (8, 128)-aligned or equal the
+# array's.  The wrapper therefore hands the kernel every per-lane 2-D array
+# (sf, si, the event streams, dmask) as (L, 1, X) with a (1, 1, X) block;
+# the carry's external (L, X) layout is unchanged.
 
 SLOTF_CLOSES, SLOTF_OPEN_TIME, SLOTF_COLS = 0, 1, 8
 (SLOTI_COUNTS, SLOTI_ALIVE, SLOTI_OSEQ, SLOTI_ASEQ, SLOTI_TAG,
@@ -432,6 +443,13 @@ REPLAY_EV_I = {"score": (), "cbd": ("cat",), "hybrid": ("key", "cls"),
 REPLAY_EV_F = {"score": (), "cbd": (), "hybrid": ("thr",),
                "rcp": ("p2err",), "la": (), "adaptive": ("errmax",)}
 _REPLAY_EXTRA_CARRY = {"hybrid": ("hagg",), "rcp": ("ragg", "ron")}
+_SMEM_CARRY = ("sf", "si")
+# v5e has 128 MiB of VMEM per core and a 16 MiB default scoped limit.  The
+# replay block raises the limit to what its blocks need plus headroom for
+# the compiler's own temporaries, and refuses geometries past the cap.
+VMEM_DEFAULT_BYTES = 16 * 2 ** 20
+VMEM_HEADROOM_BYTES = 12 * 2 ** 20
+VMEM_CAP_BYTES = 96 * 2 ** 20
 
 
 def replay_carry_names(family: str):
@@ -468,17 +486,21 @@ def _replay_block_kernel(*refs, family: str, policy: str, n: int, d: int,
     c = dict(zip(names, refs[k + 2:k + 2 + nc]))
 
     # one HBM->VMEM copy per block: every event below reads and writes the
-    # (aliased) out blocks only
+    # (aliased) out blocks only.  SMEM holds scalars only, so the (1, 1, 8)
+    # scalar carries copy element by element.
     for nm in names:
-        c[nm][...] = cin[nm][...]
+        if nm in _SMEM_CARRY:
+            for col in range(c[nm].shape[2]):
+                c[nm][0, 0, col] = cin[nm][0, 0, col]
+        else:
+            c[nm][...] = cin[nm][...]
 
     Np = c["loads"].shape[1]
-    nmax = c["itemi"].shape[1]
     rowsN = jax.lax.broadcasted_iota(i32, (Np, 1), 0)
     rowmask = rowsN < n
-    rowsI = jax.lax.broadcasted_iota(i32, (nmax, 1), 0)
     rowsK = jax.lax.broadcasted_iota(i32, (KCAT, 1), 0)
-    dm = dmask_ref[...]                                   # (1, dpad)
+    colsI = jax.lax.broadcasted_iota(i32, (1, ITEMI_COLS), 1)
+    dm = dmask_ref[0]                                     # (1, dpad)
 
     def scol_i(col):
         return c["sloti"][0, :, col:col + 1]              # (Np, 1) i32
@@ -496,14 +518,18 @@ def _replay_block_kernel(*refs, family: str, policy: str, n: int, d: int,
         return jnp.sum(jnp.where(rowsN == b, colv, zero))
 
     def at_item(col, j):
-        return jnp.sum(jnp.where(rowsI == j, c["itemi"][0, :, col:col + 1],
-                                 0))
+        row = c["itemi"][0, pl.ds(j, 1), :]               # (1, ITEMI_COLS)
+        return jnp.sum(jnp.where(colsI == col, row, 0))
+
+    def set_item(col, j, v):
+        row = c["itemi"][0, pl.ds(j, 1), :]
+        c["itemi"][0, pl.ds(j, 1), :] = jnp.where(colsI == col, v, row)
 
     def body(e, _):
-        kind = evi["kind"][0, e]
-        j = evi["item"][0, e]
-        t = evf["t"][0, e]
-        pdep = evf["pdep"][0, e]
+        kind = evi["kind"][0, 0, e]
+        j = evi["item"][0, 0, e]
+        t = evf["t"][0, 0, e]
+        pdep = evf["pdep"][0, 0, e]
         size = size_ref[0, pl.ds(e, 1), :]                # (1, dpad)
 
         def select(pol, cmask, excl=None):
@@ -515,7 +541,7 @@ def _replay_block_kernel(*refs, family: str, policy: str, n: int, d: int,
             Deliberately a third expression of the shared scoring
             semantics (per-lane (Np, 1) columns here vs the tiled
             (1, bn) SMEM-register select kernel): the three stay pinned
-            together by the shared SCORE_*/F32_EPS/IBIG constants and the
+            together by the shared SCORE_*/FIT_CAP/IBIG constants and the
             bitwise parity matrix in tests/test_fitscore_select.py +
             tests/test_replay_block.py - any drift fails those, so edit
             all three together when touching a policy's score."""
@@ -523,7 +549,7 @@ def _replay_block_kernel(*refs, family: str, policy: str, n: int, d: int,
             cnt = scol_i(SLOTI_COUNTS)
             oseq = scol_i(SLOTI_OSEQ)
             closes = scol_f(SLOTF_CLOSES)
-            feas = jnp.all(size <= 1.0 - loads2 + F32_EPS, axis=1,
+            feas = jnp.all(size <= FIT_CAP - loads2, axis=1,
                            keepdims=True) & \
                 (scol_i(SLOTI_ALIVE) > 0) & rowmask
             if excl is not None:
@@ -587,7 +613,7 @@ def _replay_block_kernel(*refs, family: str, policy: str, n: int, d: int,
             cnt = scol_i(SLOTI_COUNTS) - rm.astype(i32)
             closing = at_slot(cnt, b, 0) == 0
             ot_b = at_slot(scol_f(SLOTF_OPEN_TIME), b, 0.0)
-            c["sf"][0, SF_USAGE] = c["sf"][0, SF_USAGE] + \
+            c["sf"][0, 0, SF_USAGE] = c["sf"][0, 0, SF_USAGE] + \
                 jnp.where(closing, t - ot_b, 0.0)
             loads2 = c["loads"][0]
             loads2 = jnp.where(rm, loads2 - size, loads2)
@@ -600,15 +626,15 @@ def _replay_block_kernel(*refs, family: str, policy: str, n: int, d: int,
                                  scol_f(SLOTF_CLOSES)))
 
             if family == "hybrid":
-                keyj = evi["key"][0, e]
+                keyj = evi["key"][0, 0, e]
                 wasg = at_item(ITEMI_AUX, j) > 0
                 row = c["hagg"][0, pl.ds(keyj, 1), :]
                 c["hagg"][0, pl.ds(keyj, 1), :] = jnp.maximum(
                     row - jnp.where(wasg, size, 0.0), 0.0)
             elif family == "rcp":
-                catj = evi["cat"][0, e]
+                catj = evi["cat"][0, 0, e]
                 locd = at_item(ITEMI_AUX, j)
-                base = c["si"][0, SI_BASE]
+                base = c["si"][0, 0, SI_BASE]
                 has_base = base >= 0
                 gen_row = c["ragg"][0, pl.ds(catj, 1), :]
                 c["ragg"][0, pl.ds(catj, 1), :] = jnp.maximum(
@@ -633,13 +659,13 @@ def _replay_block_kernel(*refs, family: str, policy: str, n: int, d: int,
                                  jnp.maximum(bcat - sz_b, 0.0), bcat)
                 c["ragg"][0, 2 * KCAT:3 * KCAT, :] = jnp.where(
                     base_closed, 0.0, bcat)
-                c["si"][0, SI_BASE] = jnp.where(base_closed, -1, base)
+                c["si"][0, 0, SI_BASE] = jnp.where(base_closed, -1, base)
                 if adaptive_alpha and learn:
-                    c["sf"][0, SF_ALPHA] = jnp.maximum(
-                        c["sf"][0, SF_ALPHA], evf["p2err"][0, e])
+                    c["sf"][0, 0, SF_ALPHA] = jnp.maximum(
+                        c["sf"][0, 0, SF_ALPHA], evf["p2err"][0, 0, e])
             elif family == "adaptive" and learn:
-                c["sf"][0, SF_ERR] = jnp.maximum(c["sf"][0, SF_ERR],
-                                                 evf["errmax"][0, e])
+                c["sf"][0, 0, SF_ERR] = jnp.maximum(c["sf"][0, 0, SF_ERR],
+                                                 evf["errmax"][0, 0, e])
 
         @pl.when(kind == DEPARTURE_KIND)
         def _dep():
@@ -657,7 +683,7 @@ def _replay_block_kernel(*refs, family: str, policy: str, n: int, d: int,
                 b, found, no_free = select(policy, None, excl)
 
             elif family == "cbd":
-                catj = evi["cat"][0, e]
+                catj = evi["cat"][0, 0, e]
                 b, found, no_free = select("first_fit", tag == catj, excl)
 
                 def post(b, rm, found):
@@ -665,9 +691,9 @@ def _replay_block_kernel(*refs, family: str, policy: str, n: int, d: int,
                                jnp.where(rm & ~found, catj, tag))
 
             elif family == "hybrid":
-                keyj = evi["key"][0, e]
-                clsj = evi["cls"][0, e]
-                thrj = evf["thr"][0, e]
+                keyj = evi["key"][0, 0, e]
+                clsj = evi["cls"][0, 0, e]
+                thrj = evf["thr"][0, 0, e]
                 aggrow = c["hagg"][0, pl.ds(keyj, 1), :]
                 after = aggrow + size
                 if direct_sum:
@@ -684,24 +710,22 @@ def _replay_block_kernel(*refs, family: str, policy: str, n: int, d: int,
                                jnp.where(rm & ~found, wanted, tag))
                     c["hagg"][0, pl.ds(keyj, 1), :] = aggrow + \
                         jnp.where(is_gen, size, 0.0)
-                    aux = c["itemi"][0, :, ITEMI_AUX:ITEMI_AUX + 1]
-                    c["itemi"][0, :, ITEMI_AUX:ITEMI_AUX + 1] = jnp.where(
-                        rowsI == j, is_gen.astype(i32), aux)
+                    set_item(ITEMI_AUX, j, is_gen.astype(i32))
 
             elif family == "rcp":
-                catj = evi["cat"][0, e]
-                largej = evi["large"][0, e] > 0
-                x = jnp.maximum(evi["x"][0, e], 1).astype(f32)
-                coef = c["sf"][0, SF_ALPHA] if adaptive_alpha else 1.0
+                catj = evi["cat"][0, 0, e]
+                largej = evi["large"][0, 0, e] > 0
+                x = jnp.maximum(evi["x"][0, 0, e], 1).astype(f32)
+                coef = c["sf"][0, 0, SF_ALPHA] if adaptive_alpha else 1.0
                 thr = coef / jnp.sqrt(x)
                 gen_row = c["ragg"][0, pl.ds(catj, 1), :]
                 fits_gen = jnp.max(gen_row + size) <= thr + F32_EPS
-                base = c["si"][0, SI_BASE]
+                base = c["si"][0, 0, SI_BASE]
                 has_base = base >= 0
                 base_loads = c["loads"][0, pl.ds(jnp.maximum(base, 0), 1), :]
                 base_fits = jnp.where(
                     has_base,
-                    jnp.all(size <= 1.0 - base_loads + F32_EPS), True)
+                    jnp.all(size <= FIT_CAP - base_loads), True)
                 if excl is not None:
                     # migrate off the base bin itself: the re-place must
                     # not target its own source (matches the host oracle,
@@ -745,12 +769,10 @@ def _replay_block_kernel(*refs, family: str, policy: str, n: int, d: int,
                         jnp.where(d_base, size, 0.0)
                     onc = jnp.where(rowsK == catj,
                                     oncol | d_catf.astype(i32), oncol)
-                    aux = c["itemi"][0, :, ITEMI_AUX:ITEMI_AUX + 1]
-                    locv = jnp.where(
+                    set_item(ITEMI_AUX, j, jnp.where(
                         d_gen, LOC_G,
                         jnp.where(d_base, LOC_B,
-                                  jnp.where(d_large, LOC_L, LOC_C)))
-                    aux = jnp.where(rowsI == j, locv, aux)
+                                  jnp.where(d_large, LOC_L, LOC_C))))
                     # base conversion (paper §VI-A): base exceeded 1/2 ->
                     # becomes a category bin of its dominant member
                     # category, which turns ON
@@ -773,18 +795,19 @@ def _replay_block_kernel(*refs, family: str, policy: str, n: int, d: int,
                     catblk = jnp.where(conv, catblk + bcat, catblk)
                     catblk = jnp.where(rowsK == catj, cat_row, catblk)
                     c["ragg"][0, KCAT:2 * KCAT, :] = catblk
-                    aux = jnp.where(conv & (aux == LOC_B), LOC_C, aux)
+                    aux = c["itemi"][0, :, ITEMI_AUX:ITEMI_AUX + 1]
+                    c["itemi"][0, :, ITEMI_AUX:ITEMI_AUX + 1] = jnp.where(
+                        conv & (aux == LOC_B), LOC_C, aux)
                     set_scol_i(SLOTI_TAG, tag1)
                     c["ron"][0, :, 0:1] = onc
-                    c["itemi"][0, :, ITEMI_AUX:ITEMI_AUX + 1] = aux
                     c["ragg"][0, 2 * KCAT:3 * KCAT, :] = jnp.where(
                         conv, 0.0, bcat)
                     c["ragg"][0, RAGG_BASE:RAGG_BASE + 1, :] = jnp.where(
                         conv, 0.0, base_row)
-                    c["si"][0, SI_BASE] = jnp.where(conv, -1, base_a)
+                    c["si"][0, 0, SI_BASE] = jnp.where(conv, -1, base_a)
 
             elif family == "la":
-                icat = evi["cat"][0, e]
+                icat = evi["cat"][0, 0, e]
                 remt = jnp.maximum(scol_f(SLOTF_CLOSES), t) - t   # (Np, 1)
                 if la_mode == "binary":
                     bincat = (remt >= la_split).astype(i32)
@@ -801,7 +824,7 @@ def _replay_block_kernel(*refs, family: str, policy: str, n: int, d: int,
                 no_free = ra[2]
 
             else:   # adaptive: regime-switch on the carried departure error
-                err = c["sf"][0, SF_ERR]
+                err = c["sf"][0, 0, SF_ERR]
                 kreg = jnp.where(err < low, 0, jnp.where(err < high, 1, 2))
                 r0 = select("nrt_prioritized", None, excl)
                 r1 = select("greedy", None, excl)
@@ -814,7 +837,7 @@ def _replay_block_kernel(*refs, family: str, policy: str, n: int, d: int,
 
             # ---- shared commit
             rm = rowsN == b
-            seq = c["si"][0, SI_SEQ]
+            seq = c["si"][0, 0, SI_SEQ]
             loads2 = c["loads"][0]
             c["loads"][0, :, :] = jnp.where(rm, loads2 + size, loads2)
             set_scol_i(SLOTI_COUNTS, scol_i(SLOTI_COUNTS) + rm.astype(i32))
@@ -831,14 +854,12 @@ def _replay_block_kernel(*refs, family: str, policy: str, n: int, d: int,
                 jnp.maximum(jnp.where(found, closes, SCORE_NEG),
                             jnp.maximum(pdep, t)),
                 closes))
-            place = c["itemi"][0, :, ITEMI_PLACE:ITEMI_PLACE + 1]
-            c["itemi"][0, :, ITEMI_PLACE:ITEMI_PLACE + 1] = jnp.where(
-                rowsI == j, b, place)
-            c["si"][0, SI_OPENED] = c["si"][0, SI_OPENED] + \
+            set_item(ITEMI_PLACE, j, b)
+            c["si"][0, 0, SI_OPENED] = c["si"][0, 0, SI_OPENED] + \
                 (~found).astype(i32)
-            c["si"][0, SI_OVERFLOW] = c["si"][0, SI_OVERFLOW] | \
+            c["si"][0, 0, SI_OVERFLOW] = c["si"][0, 0, SI_OVERFLOW] | \
                 ((~found) & no_free).astype(i32)
-            c["si"][0, SI_SEQ] = seq + 1
+            c["si"][0, 0, SI_SEQ] = seq + 1
             if post is not None:
                 post(b, rm, found)
 
@@ -896,24 +917,32 @@ def fitscore_replay_block(carry, ev_i, ev_f, ev_size, dmask, *, family: str,
     assert set(names) == set(carry), (names, sorted(carry))
     ev_i_names = ("kind", "item") + REPLAY_EV_I[family]
     ev_f_names = ("t", "pdep") + REPLAY_EV_F[family]
-    f32, i32 = jnp.float32, jnp.int32
     L, T, dpad = ev_size.shape
-    smem = ("sf", "si")
-
-    def carry_spec(a):
-        nd = a.ndim
-        if nd == 2:
-            return pl.BlockSpec((1,) + a.shape[1:], lambda b: (b, 0),
-                                memory_space=pltpu.SMEM)
-        return pl.BlockSpec((1,) + a.shape[1:], lambda b: (b, 0, 0))
-
     carr = [carry[nm] for nm in names]
-    in_specs = [carry_spec(a) for a in carr]
-    in_specs += [pl.BlockSpec((1, T), lambda b: (b, 0),
-                              memory_space=pltpu.SMEM)
-                 for _ in ev_i_names + ev_f_names]
-    in_specs += [pl.BlockSpec((1, T, dpad), lambda b: (b, 0, 0)),
-                 pl.BlockSpec((1, dpad), lambda b: (b, 0))]
+    vmem = replay_block_vmem_bytes({nm: a.shape for nm, a in carry.items()},
+                                   T)
+    if not interpret and vmem > VMEM_CAP_BYTES:
+        raise ValueError(
+            f"replay block geometry does not fit VMEM: family={family} "
+            f"L={L} item_rows={carry['itemi'].shape[1]} "
+            f"Np={carry['loads'].shape[1]} dpad={dpad} T={T} needs "
+            f"{vmem / 2**20:.1f} MiB > {VMEM_CAP_BYTES / 2**20:.0f} MiB; "
+            "use a smaller item pool (repro.stream item_rows) or max_bins")
+
+    def row(a):   # (L, X) -> (L, 1, X): unit middle axis for tiling
+        return a.reshape(a.shape[0], 1, a.shape[-1])
+
+    def spec(shape, smem=False):
+        nz = (0,) * (len(shape) - 1)
+        return pl.BlockSpec((1,) + tuple(shape[1:]), lambda b: (b,) + nz,
+                            memory_space=pltpu.SMEM if smem else None)
+
+    args = [row(a) if nm in _SMEM_CARRY else a for nm, a in zip(names, carr)]
+    cspecs = [spec(a.shape, nm in _SMEM_CARRY) for nm, a in zip(names, args)]
+    streams = [row(ev_i[nm]) for nm in ev_i_names] + \
+        [row(ev_f[nm]) for nm in ev_f_names]
+    in_specs = cspecs + [spec(a.shape, True) for a in streams] + \
+        [spec(ev_size.shape), spec((L, 1, dpad))]
     kernel = functools.partial(
         _replay_block_kernel, family=family, policy=policy, n=n, d=d, T=T,
         large_bins=large_bins, adaptive_alpha=adaptive_alpha,
@@ -924,14 +953,37 @@ def fitscore_replay_block(carry, ev_i, ev_f, ev_size, dmask, *, family: str,
         kernel,
         grid=(L,),
         in_specs=in_specs,
-        out_specs=[carry_spec(a) for a in carr],
-        out_shape=[jax.ShapeDtypeStruct(a.shape, a.dtype) for a in carr],
+        out_specs=cspecs,
+        out_shape=[jax.ShapeDtypeStruct(a.shape, a.dtype) for a in args],
         input_output_aliases={idx: idx for idx, nm in enumerate(names)
-                              if nm not in smem},
+                              if nm not in _SMEM_CARRY},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=max(vmem + VMEM_HEADROOM_BYTES,
+                                 VMEM_DEFAULT_BYTES)),
         interpret=interpret,
-    )(*carr, *(ev_i[nm] for nm in ev_i_names),
-      *(ev_f[nm] for nm in ev_f_names), ev_size, dmask)
-    return dict(zip(names, outs))
+    )(*args, *streams, ev_size, row(dmask))
+    return {nm: o.reshape(carry[nm].shape) for nm, o in zip(names, outs)}
+
+
+def _vmem_tile_bytes(shape) -> int:
+    """Bytes of one 32-bit VMEM buffer of ``shape`` after (8, 128) tiling."""
+    *lead, r, c = shape
+    lead_n = 1
+    for x in lead:
+        lead_n *= x
+    return lead_n * (-(-r // 8) * 8) * (-(-c // 128) * 128) * 4
+
+
+def replay_block_vmem_bytes(carry_shapes, T: int) -> int:
+    """VMEM one ``fitscore_replay_block`` grid step holds: every VMEM carry
+    block in and out, plus the event-size and dmask blocks, each double
+    buffered by the pipeline.  ``carry_shapes`` maps carry names to their
+    (L, ...) shapes."""
+    dpad = carry_shapes["loads"][2]
+    carry = sum(_vmem_tile_bytes(shp[1:]) for nm, shp in carry_shapes.items()
+                if nm not in _SMEM_CARRY)
+    return 2 * (2 * carry + _vmem_tile_bytes((T, dpad)) +
+                _vmem_tile_bytes((1, dpad)))
 
 
 def fitscore_replay_chunk(carry, ev_i, ev_f, ev_size, dmask, *,

@@ -29,18 +29,36 @@ def _use_pallas(impl: str) -> bool:
     return False
 
 
+def _interpret(impl: str) -> bool:
+    """Whether the event-blocked replay kernel runs in interpret mode: only
+    when asked (``"pallas_interpret"``) or under ``"auto"`` off-TPU (the
+    CPU test harness).  That kernel has no jnp twin, so ``"jnp"`` is
+    refused rather than quietly interpreted."""
+    if impl == "pallas_interpret":
+        return True
+    if impl == "pallas":
+        return False
+    if impl == "auto":
+        return jax.default_backend() != "tpu"
+    raise ValueError(
+        f"impl={impl!r}: the blocked replay kernel has no jnp twin; use "
+        "'pallas', 'pallas_interpret' or 'auto'")
+
+
 def resolved_select_impl(impl: str, block: bool = False) -> str:
     """The engine that will *actually* serve a ``fitscore_select`` /
     ``fitscore_select_block`` call with this ``impl`` argument: "pallas"
     (native kernel), "pallas_interpret" (kernel body interpreted) or "jnp"
-    (the ``_select_slot`` twin).  ``impl="auto"`` silently falls back to
-    jnp off-TPU - and the blocked select has no jnp twin, so it runs the
-    kernel in interpret mode instead.  Surfacing the resolved name (the
-    serving scheduler's span backend tag, ``obs`` counter suffix) makes
-    that fallback visible instead of just slow."""
+    (the ``_select_slot`` twin).  ``impl="auto"`` resolves to the native
+    kernel on TPU; off-TPU the per-event select runs its jnp twin and the
+    blocked select (no jnp twin) the kernel in interpret mode.  The
+    resolved name tags the serving scheduler's spans and ``obs`` counters,
+    so a run can prove which engine served it."""
+    if block:
+        return "pallas_interpret" if _interpret(impl) else "pallas"
     if _use_pallas(impl):
         return "pallas"
-    if impl == "pallas_interpret" or block:
+    if impl == "pallas_interpret":
         return "pallas_interpret"
     return "jnp"
 
@@ -197,7 +215,7 @@ def _fitscore_replay_dispatch_jit(carry, ev_i, ev_f, ev_size, dmask, *,
         large_bins=spec.large_bins, adaptive_alpha=spec.adaptive_alpha,
         direct_sum=spec.direct_sum, la_mode=spec.la_mode,
         la_split=LA_BINARY_SPLIT, low=spec.low, high=spec.high,
-        migrate=migrate, interpret=not _use_pallas(impl))
+        migrate=migrate, interpret=_interpret(impl))
 
 
 def dispatch_trace_count() -> int:
@@ -266,7 +284,7 @@ def _fitscore_select_block_jit(loads, alive, open_seq, access_seq, closes,
     out = fitscore_replay_block(
         carry, ev_i, ev_f, ev_size, dmask, family=family,
         policy=policy if family == "score" else "first_fit", n=n, d=d,
-        interpret=not _use_pallas(impl))
+        interpret=_interpret(impl))
     slot = out["itemi"][0, 0, ITEMI_PLACE]
     found = out["si"][0, SI_OPENED] == 0
     return slot, found

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..compat import shard_map
+from jax import shard_map
 from .config import ModelConfig
 
 

@@ -21,7 +21,7 @@ jitters the ``slow`` delay), so a chaos test replays identically.
 Fault kinds (mirroring what real runs die of):
 
   * ``xla``      - raises ``InjectedFault`` with an ``INTERNAL:`` message
-                   (an XlaRuntimeError-shaped device failure; degradable),
+                   (a device failure as jax reports it; degradable),
   * ``oom``      - raises with ``RESOURCE_EXHAUSTED:`` (transient: the
                    guard retries it before degrading),
   * ``error``    - a plain injected crash (degradable, not transient),
@@ -53,7 +53,7 @@ from .. import obs
 FAULT_KINDS = ("xla", "oom", "error", "slow", "truncate", "kill")
 
 _MESSAGES = {
-    "xla": "INTERNAL: injected XlaRuntimeError at seam {site!r}",
+    "xla": "INTERNAL: injected device fault at seam {site!r}",
     "oom": "RESOURCE_EXHAUSTED: injected OOM at seam {site!r}",
     "error": "injected fault at seam {site!r}",
 }
@@ -61,9 +61,9 @@ _MESSAGES = {
 
 class InjectedFault(RuntimeError):
     """A scripted failure raised by the harness (stands in for
-    XlaRuntimeError and friends; ``guard.is_degradable`` treats it as a
-    device failure, and ``guard.is_transient`` classifies by the same
-    status markers real jax errors carry)."""
+    ``jax.errors.JaxRuntimeError`` and friends; ``guard.is_degradable``
+    treats it as a device failure, and ``guard.is_transient`` classifies
+    by the same status markers real jax errors carry)."""
 
 
 @dataclasses.dataclass(frozen=True)

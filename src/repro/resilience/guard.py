@@ -4,7 +4,7 @@ Two layers, composed by ``run_ladder``:
 
   * ``guarded_call`` retries *transient* device failures (RESOURCE_
     EXHAUSTED / UNAVAILABLE / DEADLINE_EXCEEDED / ABORTED - the status
-    markers real XlaRuntimeErrors carry) on the same execution plan, with
+    markers ``jax.errors.JaxRuntimeError`` carries) on the same plan, with
     deterministic jittered exponential backoff.  Counted as
     ``resilience.retry``.
   * When retries exhaust (or the failure is non-transient but still a
@@ -18,8 +18,9 @@ Two layers, composed by ``run_ladder``:
     ``resilience.degrade_<from>_<to>``.
 
 Failures that are neither transient nor degradable (assertion errors,
-shape errors, KeyboardInterrupt) propagate immediately - the ladder
-exists for *device* trouble, not for bugs.
+shape errors, Pallas lowering and compile errors, KeyboardInterrupt)
+propagate immediately - the ladder exists for *device* trouble, not for
+bugs, so a kernel the chip's compiler refuses never runs silently in jnp.
 
 Backoff sleeps scale with env ``REPRO_RESILIENCE_BACKOFF_SCALE`` (tests
 set 0 to run the retry logic without the waiting).
@@ -38,25 +39,32 @@ from .faults import InjectedFault
 # status markers of failures worth retrying on the same plan
 TRANSIENT_MARKERS = ("RESOURCE_EXHAUSTED", "DEADLINE_EXCEEDED",
                      "UNAVAILABLE", "ABORTED")
-# ... plus markers that say "the device/runtime broke" (degradable but
-# not worth retrying on the same plan)
-_DEVICE_MARKERS = TRANSIENT_MARKERS + ("INTERNAL", "XLA", "pallas")
+# markers of a program the compiler refused (Mosaic kernel compile, VMEM or
+# HBM overflow at compile time): a bug in the program, never device trouble
+COMPILE_MARKERS = ("Mosaic", "compile", "vmem", "Scoped allocation")
+
+
+def is_compile_error(exc: BaseException) -> bool:
+    """The compiler refused the program: no rung below may hide that."""
+    return any(m in str(exc) for m in COMPILE_MARKERS)
 
 
 def is_transient(exc: BaseException) -> bool:
     """Worth retrying on the same execution plan."""
-    return isinstance(exc, Exception) and \
+    return isinstance(exc, Exception) and not is_compile_error(exc) and \
         any(m in str(exc) for m in TRANSIENT_MARKERS)
 
 
 def is_degradable(exc: BaseException) -> bool:
-    """A device/runtime failure a lower ladder rung can route around."""
+    """A device/runtime failure a lower ladder rung can route around: a
+    scripted fault, or a ``jax.errors.JaxRuntimeError`` raised while the
+    compiled program ran.  Lowering and compile errors are bugs and
+    propagate."""
+    import jax
     if isinstance(exc, InjectedFault):
         return True
-    if type(exc).__name__ == "XlaRuntimeError":
-        return True
-    return isinstance(exc, RuntimeError) and \
-        any(m in str(exc) for m in _DEVICE_MARKERS)
+    return isinstance(exc, jax.errors.JaxRuntimeError) and \
+        not is_compile_error(exc)
 
 
 def backoff_delay(site: str, attempt: int, base: float = 0.05,

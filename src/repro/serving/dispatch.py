@@ -35,10 +35,11 @@ counters (off ``kernels.ops.dispatch_trace_count``) are the monitored
 invariant, gated in CI like ``perf/sweep_retrace_6x2v12x1``.
 
 **Degradation ladder.**  Every dispatch crosses the ``serving.select``
-fault seam per rung: the configured block engine, then the kernel in
-interpret mode (when the configured engine was the native kernel), then a
-per-event T=1 loop - each step ticking a
-``resilience.degrade_dispatch_<from>_<to>`` counter.  Overflow (the pool
+fault seam per rung: the configured block engine, then the same engine
+in a per-event T=1 loop - the step ticking a
+``resilience.degrade_dispatch_<from>_<to>`` counter.  Only device faults
+(``guard.is_degradable``) step down; a kernel the compiler refuses is a
+bug and propagates.  Overflow (the pool
 ran out of slots) regrows the carry (``grow_live_carry``, doubling
 ``max_bins``) and replays the failed block plus everything newer from the
 saved pre-block carries - the streams are kept host-side until their
@@ -273,13 +274,10 @@ class BlockDispatcher:
         return ev_i, ev_f, size
 
     def _rungs(self) -> List[Tuple[str, str]]:
-        from ..kernels.ops import resolved_select_impl
-        resolved = resolved_select_impl(self.impl, block=True)
-        rungs = [("block", self.impl)]
-        if resolved == "pallas":
-            rungs.append(("block_interpret", "pallas_interpret"))
-        rungs.append(("events", "pallas_interpret"))
-        return rungs
+        """The whole block, then the same engine one event per call.  Both
+        rungs run the configured engine: a device fault never moves a
+        native kernel into interpret mode."""
+        return [("block", self.impl), ("events", self.impl)]
 
     def _dispatch(self, carry, streams, T: int, start_rung: int = 0,
                   migrate: bool = False) -> Tuple[dict, int]:

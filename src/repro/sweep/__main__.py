@@ -36,6 +36,20 @@ def _pred(token: str) -> PredModel:
     return PredModel(kind, float(param) if param else 0.0)
 
 
+def _platform() -> str:
+    """The default JAX platform, asked in a short-lived child so that this
+    process never starts a backend (and never holds a chip its workers
+    would need)."""
+    if os.environ.get("JAX_PLATFORMS", "").split(",")[0] == "cpu":
+        return "cpu"
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import jax; print(jax.default_backend())"],
+        capture_output=True, text=True, timeout=300)
+    return out.stdout.strip().splitlines()[-1] if out.stdout.strip() \
+        else "unknown"
+
+
 def main(argv=None, prog: str = "python -m repro sweep") -> None:
     ap = argparse.ArgumentParser(
         prog=prog,
@@ -112,6 +126,12 @@ def main(argv=None, prog: str = "python -m repro sweep") -> None:
         # journal+lock merging produce the single combined record set
         if args.no_store:
             raise SystemExit("--hosts needs a store to merge results into")
+        if _platform() == "tpu":
+            raise SystemExit(
+                "--hosts starts one process per slice, and a TPU chip "
+                "belongs to one process: drop --hosts and let one process "
+                "drive every local chip (--shard auto shards the lanes "
+                "over them)")
         base, skip = [], False
         for a in (argv if argv is not None else sys.argv[1:]):
             if skip:

@@ -30,7 +30,7 @@ paths are bit-identical on fp32-exact instances (tests/test_sweep.py,
 tests/test_sweep_categories.py, tests/test_replay_block.py).
 
 Sharding: when more than one local device is visible, the lane axis is
-sharded across them via ``compat.shard_map`` (lanes padded to a device
+sharded across them via ``jax.shard_map`` (lanes padded to a device
 multiple; each device replays its lane shard independently - the replay has
 no cross-lane communication, so the map is embarrassingly parallel).  With
 one device the plain single-device path runs, unchanged.
@@ -86,8 +86,7 @@ def _simulate_lanes_impl(sizes, times, kinds, items, pdeps, dmask, arrivals,
                          trace_level: int = 0):
     """Flattened-lane replay: ``pdeps`` is (L, n_max) - exactly one
     prediction row per lane.  This is the shard_map body: a single
-    lane-batched scan (nested vmaps inside a shard body trip jax 0.4.x's
-    sharding propagation - invalid tile_assignment at HLO verification)."""
+    lane-batched scan."""
     res = _replay_batch(
         sizes, times, kinds, items, pdeps, dmask, arrivals, rdeps, n_items,
         policy=policy, max_bins=max_bins, backend=backend,
@@ -151,7 +150,7 @@ def _simulate_batch_sharded(sizes, times, kinds, items, pdeps, dmask,
     lanes with the plain single-device computation - no collectives."""
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from ..compat import shard_map
+    from jax import shard_map
     mesh = Mesh(np.asarray(jax.local_devices()[:ndev]), ("lanes",))
     f = shard_map(
         partial(_simulate_lanes_impl, policy=policy, max_bins=max_bins,

@@ -17,7 +17,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from ..compat import shard_map
+from jax import shard_map
 
 
 def _quant(x: jax.Array) -> Tuple[jax.Array, jax.Array]:

@@ -330,7 +330,7 @@ def test_serving_drain_pass_moves_migrant():
     caps = ReplicaCapacity(slots=4, kv_tokens=1 << 20,
                            prefill_budget=1 << 20)
     disp = BlockDispatcher("first_fit", caps, tps=50.0, max_bins=8,
-                           max_items=16, impl="jnp")
+                           max_items=16, impl="pallas_interpret")
     for rid in range(4):           # fill replica 0 (4 x 0.25 slots)
         disp.enqueue_arrival(Request(rid, float(rid), 64, 64), float(rid))
     disp.enqueue_arrival(Request(4, 4.0, 64, 64), 4.0)   # opens replica 1
@@ -357,7 +357,7 @@ def test_serving_drain_respects_budget():
     caps = ReplicaCapacity(slots=4, kv_tokens=1 << 20,
                            prefill_budget=1 << 20)
     disp = BlockDispatcher("first_fit", caps, tps=50.0, max_bins=8,
-                           max_items=16, impl="jnp")
+                           max_items=16, impl="pallas_interpret")
     for rid in range(4):
         disp.enqueue_arrival(Request(rid, float(rid), 64, 64), float(rid))
     for rid in (4, 5):

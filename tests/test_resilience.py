@@ -12,6 +12,7 @@ import os
 import subprocess
 import sys
 
+import jax
 import numpy as np
 import pytest
 
@@ -568,3 +569,21 @@ def test_obs_instant_point_events():
     assert evs[0]["ph"] == "i" and evs[0]["dur"] == 0.0
     assert evs[0]["args"] == {"foo": 1}
     assert obs.chrome_trace_events(evs)["traceEvents"][0]["ph"] == "i"
+
+
+@pytest.mark.parametrize("exc,degradable", [
+    (jax.errors.JaxRuntimeError("INTERNAL: TPU halted"), True),
+    (jax.errors.JaxRuntimeError(
+        "INTERNAL: Mosaic failed to compile TPU kernel"), False),
+    (jax.errors.JaxRuntimeError(
+        "RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem"),
+     False),
+    (RuntimeError("INTERNAL: pallas XLA"), False),
+    (ValueError("block shape not divisible by 8 and 128"), False),
+    (faults.InjectedFault("INTERNAL: injected"), True),
+])
+def test_only_runtime_device_faults_degrade(exc, degradable):
+    """A program the compiler refuses is a bug: no rung may hide it."""
+    assert guard.is_degradable(exc) is degradable
+    if not degradable:
+        assert not guard.is_transient(exc)
