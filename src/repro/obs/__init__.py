@@ -4,8 +4,12 @@ One observability layer for every execution path: host-side **spans**
 (wall-clock intervals, Chrome ``trace_event`` shaped) and always-on
 **counters** from the collector; per-event **replay traces** emitted by
 the batched scan itself (``trace_level`` on ``run_batch`` /
-``Experiment.run``); JSONL / Perfetto **exporters** plus the
-``jax.profiler`` hook; and ``python -m repro obs`` to summarize a run log.
+``Experiment.run``); JSONL / Perfetto **exporters**; and ``python -m
+repro obs`` to summarize a run log.  A recorded span is also written into
+any ``jax.profiler`` trace running around it (a ``TraceAnnotation`` of its
+name on the host plane, on the device operations' clock), for a few
+microseconds a span; a span that is not recorded costs one flag check and
+never touches ``jax``.
 
 Span-name and counter glossaries live in ``sweep/README.md``.  The rules:
 counters are always on (single dict upsert); spans are recorded only
@@ -20,7 +24,7 @@ from .collector import (HIST_BOUNDS, Span, TimingStats, annotate,
                         enabled, events, instant, recording, reset, span,
                         timeit, traced)
 from .export import (chrome_trace_events, export_jsonl, export_perfetto,
-                     jax_profile, read_jsonl, summarize)
+                     read_jsonl, summarize)
 from .trace import (ReplayTrace, TraceDivergence, diff_traces, from_scan)
 
 __all__ = [
@@ -28,7 +32,7 @@ __all__ = [
     "counter_deltas", "counter_get", "counter_hist", "counter_ops",
     "counters", "disable", "enable", "enabled", "events", "instant",
     "recording", "reset", "span", "timeit", "traced",
-    "chrome_trace_events", "export_jsonl", "export_perfetto", "jax_profile",
+    "chrome_trace_events", "export_jsonl", "export_perfetto",
     "read_jsonl", "summarize",
     "ReplayTrace", "TraceDivergence", "diff_traces", "from_scan",
 ]

@@ -11,8 +11,14 @@ Two tiers, tuned so instrumentation can stay in every hot path:
     (``obs.enable()`` / ``obs.recording()`` / env ``REPRO_OBS=1``).  When
     disabled, ``span()`` returns a shared no-op object: the cost of an
     instrumented-but-disabled call site is one flag check and one function
-    call (the <2% overhead budget is asserted by
-    ``benchmarks/perf.py::obs_overhead``).
+    call, and ``jax`` is never touched (the <2% overhead budget is asserted
+    by ``benchmarks/perf.py::obs_overhead``).  A recorded span also opens a
+    ``jax.profiler.TraceAnnotation`` of its name, so every span lands in
+    the host plane of any ``jax.profiler`` trace running around it, on the
+    same clock as the device operations.  That costs a few microseconds a
+    span when recorded (one native TraceMe, nearly free while no profiler
+    session is active); ``jax.profiler`` is imported on the first recorded
+    span.
 
 Spans must never be opened *inside* a jitted/vmapped/shard_mapped
 computation - a traced function body runs once at trace time, so a span
@@ -128,14 +134,27 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+_TraceAnnotation = None       # jax.profiler.TraceAnnotation, bound lazily
+
+
+def _annotation(name: str):
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(name)
+
+
 class Span:
-    __slots__ = ("name", "cat", "args", "_t0")
+    __slots__ = ("name", "cat", "args", "_t0", "_ann")
 
     def __init__(self, name: str, cat: str, args: dict):
         self.name, self.cat, self.args = name, cat, args
 
     def __enter__(self):
         _TLS.stack.append(self)
+        self._ann = _annotation(self.name)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -147,6 +166,7 @@ class Span:
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
         _TLS.stack.pop()
         ev = {"name": self.name, "cat": self.cat, "ph": "X",
               "ts": (self._t0 - _T0) * 1e6, "dur": (t1 - self._t0) * 1e6,

@@ -1,5 +1,6 @@
 """Exporters for the obs collector: JSONL run logs, Chrome/Perfetto
-``trace_event`` JSON, a text summary, and the ``jax.profiler`` hook.
+``trace_event`` JSON and a text summary.  (Recorded spans reach a
+``jax.profiler`` trace by themselves; see ``collector``.)
 
 A *run log* is line-delimited JSON: one ``{"type": "meta", ...}`` header,
 one line per span event, and a final ``{"type": "counters", ...}``
@@ -13,7 +14,6 @@ from __future__ import annotations
 import json
 import os
 import time
-from contextlib import contextmanager
 from typing import Dict, List, Optional, Tuple
 
 from . import collector
@@ -116,23 +116,3 @@ def summarize(events: Optional[List[dict]] = None,
     if not counters:
         lines.append("(no counters)")
     return "\n".join(lines)
-
-
-@contextmanager
-def jax_profile(logdir: Optional[str] = None):
-    """``jax.profiler`` start/stop around a block, recorded as a span so
-    native TPU/XLA profiles attach to the same span tree.  Active only
-    when a log dir is given (or env ``REPRO_OBS_PROFILE`` names one);
-    otherwise a no-op, so it can wrap the scan dispatch unconditionally."""
-    logdir = logdir or os.environ.get("REPRO_OBS_PROFILE", "")
-    if not logdir:
-        yield None
-        return
-    import jax
-    with collector.span("profiler.jax_trace", cat="profiler",
-                        logdir=logdir):
-        jax.profiler.start_trace(logdir)
-        try:
-            yield logdir
-        finally:
-            jax.profiler.stop_trace()

@@ -19,6 +19,12 @@ serving front end's block placement does.  ``prefetch=0`` is the
 synchronous reference (fence after every chunk), kept for the
 ``perf/stream_prefetch`` comparison.
 
+Recorded spans mark the staging boundaries: ``stream.init`` (builder,
+carry and pool of a pass), ``stream.build`` (one chunk's heap merge and
+build), ``stream.put`` (its transfer), ``stream.step`` (its dispatch) and
+``stream.fence`` (waiting on the device); ``stream.replay`` carries the
+events and chunks replayed.  One span per chunk, never one per event.
+
 Memory is O(pool): the carry, the row pool and at most ``prefetch + 1``
 staged chunks - independent of trace length.  ``peak_device_bytes``
 reports the accounted maximum.  Overflow keeps the in-memory escalation
@@ -153,14 +159,23 @@ def _nbytes(tree) -> int:
 def _replay_once(source, policy, *, chunk_events, item_rows, max_bins,
                  backend, block_events, prefetch, grow_pool,
                  collect_placements, checkpointer):
-    wl = ChunkedWorkload(source, policy, chunk_events=chunk_events,
-                         item_rows=item_rows, grow=grow_pool)
-    d = wl.d
-    rows = wl.item_rows
-    carry = replay_init_carry(policy, max_bins, d, rows, L=1,
-                              backend=backend, block_events=block_events)
-    pool = _pool_full(source) if wl.identity else _pool0(rows, d)
+    with obs.span("stream.init", max_bins=max_bins) as sp:
+        wl = ChunkedWorkload(source, policy, chunk_events=chunk_events,
+                             item_rows=item_rows, grow=grow_pool)
+        d = wl.d
+        rows = wl.item_rows
+        sp.set(item_rows=rows)
+        carry = replay_init_carry(policy, max_bins, d, rows, L=1,
+                                  backend=backend, block_events=block_events)
+        pool = _pool_full(source) if wl.identity else _pool0(rows, d)
     gen = wl.chunks()
+
+    def build():
+        with obs.span("stream.build") as sp:
+            ch = next(gen)
+            sp.set(events=ch.n_events)
+        return ch
+
     resumed_chunks = 0
     ckpt_key = None
     if checkpointer is not None:
@@ -176,7 +191,7 @@ def _replay_once(source, policy, *, chunk_events, item_rows, max_bins,
             carry, pool, resumed_chunks = state
             rows = pool["sizes"].shape[1]
             for _ in range(resumed_chunks):   # host fast-forward (cheap,
-                next(gen)                     # deterministic builder)
+                build()                       # deterministic builder)
 
     depth = max(int(prefetch), 0)
     staged: deque = deque()
@@ -187,41 +202,43 @@ def _replay_once(source, policy, *, chunk_events, item_rows, max_bins,
     done = False
     while True:
         while not done and len(staged) <= depth:
-            try:
-                ch = next(gen)
-            except StopIteration:
-                done = True
-                break
-            dev = jax.device_put((ch.times, ch.kinds, ch.items, ch.upd_idx,
-                                  ch.upd_size, ch.upd_arrival, ch.upd_rdep,
-                                  ch.upd_pdep, ch.extras, ch.freed))
+            ch = build()
+            done = ch.final
+            with obs.span("stream.put"):
+                dev = jax.device_put((ch.times, ch.kinds, ch.items,
+                                      ch.upd_idx, ch.upd_size,
+                                      ch.upd_arrival, ch.upd_rdep,
+                                      ch.upd_pdep, ch.extras, ch.freed))
             staged.append((ch, dev))
             peak = max(peak, _nbytes(carry) + _nbytes(pool) +
                        sum(_nbytes(s[1]) for s in staged))
         if not staged:
             break
         ch, dev = staged.popleft()
-        if ch.item_rows > rows:
-            # the builder outgrew the pool: pad pool + carry (one retrace)
-            obs.counter_add("stream.pool_growths")
-            rows = ch.item_rows
-            pool = _grow_pool(pool, rows)
-            carry = _grow_carry(carry, rows)
-        carry, pool, usage, opened, overflow, fp = _chunk_step(
-            carry, pool, *dev, policy=policy, max_bins=max_bins,
-            backend=backend, block_events=block_events, migrate=False,
-            harvest=collect_placements)
+        with obs.span("stream.step"):
+            if ch.item_rows > rows:
+                # the builder outgrew the pool: pad pool + carry (one retrace)
+                obs.counter_add("stream.pool_growths")
+                rows = ch.item_rows
+                pool = _grow_pool(pool, rows)
+                carry = _grow_carry(carry, rows)
+            carry, pool, usage, opened, overflow, fp = _chunk_step(
+                carry, pool, *dev, policy=policy, max_bins=max_bins,
+                backend=backend, block_events=block_events, migrate=False,
+                harvest=collect_placements)
         if collect_placements:
             harvest.append((ch.freed_seqs, fp))
         last = (usage, opened, overflow)
         nchunks += 1
         if depth == 0:
-            jax.block_until_ready(carry)   # synchronous reference mode
+            with obs.span("stream.fence"):  # synchronous reference mode
+                jax.block_until_ready(carry)
         if checkpointer is not None:
             checkpointer.maybe_save(ckpt_key, carry, pool, nchunks,
                                     final=ch.final)
 
-    usage, opened, overflow = (jax.block_until_ready(x) for x in last)
+    with obs.span("stream.fence"):
+        usage, opened, overflow = jax.block_until_ready(last)
     placements = None
     if collect_placements:
         placements = np.full(wl.n_items, -1, np.int32)
@@ -254,8 +271,9 @@ def replay_stream(source, policy: str, *, chunk_events: int = 2048,
     docstring for staging/prefetch semantics."""
     backend = resolve_backend(backend)
     policy_spec(policy)            # validate before any device work
+    events = chunks = 0
     with obs.span("stream.replay", cat="stream", policy=policy,
-                  backend=backend, chunk_events=int(chunk_events)):
+                  backend=backend, chunk_events=int(chunk_events)) as sp:
         while True:
             res = _replay_once(
                 source, policy, chunk_events=chunk_events,
@@ -264,7 +282,10 @@ def replay_stream(source, policy: str, *, chunk_events: int = 2048,
                 grow_pool=grow_pool,
                 collect_placements=collect_placements,
                 checkpointer=checkpointer)
+            events += res.n_events
+            chunks += res.n_chunks
             if not res.overflow or not auto_grow:
+                sp.set(events=events, chunks=chunks)
                 return res
             if max_bins >= max_bins_cap:
                 raise CapacityError(

@@ -348,18 +348,19 @@ def run_batch(batch: InstanceBatch, policy: str,
     arrays = (batch.sizes, batch.times, batch.kinds, batch.items, pdeps,
               batch.dmask, batch.arrivals, batch.pdeps, batch.n_items)
     trace_np = None
+    events = 0
     with obs.span("sweep.run_batch", policy=policy, backend=backend,
                   B=B, S=S) as rb_span:
         rungs = 0
         while True:
+            events += 2 * int(batch.n_items[lanes].sum(dtype=np.int64)) * S
             with obs.span("sweep.flatten"):
                 sub = tuple(jnp.asarray(a[lanes]) for a in arrays)
             obs.counter_add("sweep.device_transfer_bytes",
                             sum(int(x.nbytes) for x in sub))
             c0 = _jit_cache_entries()
             with obs.span("sweep.scan", policy=policy, max_bins=mb,
-                          lanes=int(lanes.size) * S) as sc, \
-                    obs.jax_profile():
+                          lanes=int(lanes.size) * S) as sc:
                 if consolidate is not None:
                     u, o, ov, churn = _run_consolidated(
                         sub, policy=policy, max_bins=mb, backend=backend,
@@ -404,6 +405,7 @@ def run_batch(batch: InstanceBatch, policy: str,
             mb = grow_max_bins(mb, max_bins_cap)
             rungs += 1
             obs.counter_add("sweep.overflow_rungs")
+        rb_span.set(events=events)
         if rungs:
             rb_span.set(overflow_rungs=rungs)
     trace = None if trace_np is None else from_scan(

@@ -107,6 +107,60 @@ def test_recording_spans_nesting_and_annotate():
         assert any(e["name"] == "test.deco" for e in obs.events())
 
 
+def test_recorded_span_lands_in_the_profiler_trace(tmp_path):
+    """A recorded span is written into a running jax.profiler trace, by
+    name, on the host plane (the device operations' clock); a span that
+    is not recorded is not."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.recording():
+            with obs.span("stream.build", events=3):
+                pass
+        with obs.span("test.unrecorded"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = {p.name: {e.name for line in p.lines for e in line.events}
+            for p in ProfileData.from_file(path).planes
+            if p.name.startswith("/host:")}
+    assert "stream.build" in host["/host:CPU"]
+    assert not any("test.unrecorded" in names for names in host.values())
+
+
+def test_unrecorded_span_opens_no_annotation(monkeypatch):
+    from repro.obs import collector
+    opened = []
+
+    class Spy:
+        def __init__(self, name):
+            opened.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(collector, "_TraceAnnotation", Spy)
+    prev = obs.enabled()
+    obs.enable(False)
+    try:
+        with obs.span("test.off") as sp:
+            assert sp is collector._NULL_SPAN
+    finally:
+        obs.enable(prev)
+    assert opened == []
+    with obs.recording():
+        with obs.span("test.on"):
+            pass
+    assert opened == ["test.on"]
+
+
 def test_timeit_stats_and_row():
     import os
     import sys

@@ -168,6 +168,52 @@ def test_overflow_rung_on_chunk_boundary():
     assert obs.counter_get("stream.overflow_rungs") > c0
 
 
+@pytest.mark.parametrize("prefetch", (0, 1))
+def test_recorded_spans_per_chunk(prefetch):
+    """A recorded stream opens one build, put and step span per chunk and
+    fences once (and after every chunk when synchronous); the replay span
+    carries the events and chunks; recording changes no decision."""
+    inst = _stream_instance(seed=5, n=150)
+    kw = dict(chunk_events=32, item_rows=32, max_bins=64, prefetch=prefetch,
+              collect_placements=True)
+    off = replay_stream(InstanceSource(inst), "best_fit_linf", **kw)
+    with obs.recording():
+        res = replay_stream(InstanceSource(inst), "best_fit_linf", **kw)
+        evs = obs.events()
+    names = [e["name"] for e in evs]
+    assert res.n_chunks > 1
+    assert names.count("stream.init") == 1
+    for name in ("stream.build", "stream.put", "stream.step"):
+        assert names.count(name) == res.n_chunks, name
+    assert names.count("stream.fence") == (
+        res.n_chunks + 1 if prefetch == 0 else 1)
+    builds = [e["args"]["events"] for e in evs
+              if e["name"] == "stream.build"]
+    assert sum(builds) == res.n_events
+    rep, = [e for e in evs if e["name"] == "stream.replay"]
+    assert rep["args"]["events"] == res.n_events
+    assert rep["args"]["chunks"] == res.n_chunks
+    assert (res.usage, res.opened, res.overflow, res.max_bins) == \
+        (off.usage, off.opened, off.overflow, off.max_bins)
+    assert np.array_equal(res.placements, off.placements)
+
+
+def test_recorded_events_span_every_overflow_pass():
+    """An escalating stream is replayed once per rung: the replay span
+    counts the events and chunks of every pass."""
+    inst = _one_instance(3, 40, 4, 8, 860000.0, 0.4, "dense")
+    with obs.recording():
+        res = replay_stream(InstanceSource(inst), "first_fit",
+                            chunk_events=16, item_rows=64, max_bins=4)
+        evs = obs.events()
+    passes = sum(e["name"] == "stream.init" for e in evs)
+    assert passes > 1 and res.max_bins > 4
+    rep, = [e for e in evs if e["name"] == "stream.replay"]
+    assert rep["args"]["events"] == passes * res.n_events
+    assert rep["args"]["chunks"] == sum(e["name"] == "stream.step"
+                                        for e in evs)
+
+
 def test_capacity_error_at_cap():
     inst = _one_instance(3, 40, 4, 8, 860000.0, 0.4, "dense")
     with pytest.raises(jaxsim.CapacityError):
