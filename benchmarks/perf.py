@@ -213,8 +213,9 @@ def replay_block_bytes(lanes: int = 2, n_items: int = 40, d: int = 3,
                        T: int = 8) -> List[str]:
     """Per-event-step HBM bytes moved by the compiled replay, from the
     trip-count-aware HLO cost model (``launch.hlo_cost.module_cost``): the
-    per-event kernel path streams the whole padded carry through HBM once
-    per event; the blocked path touches it once per T-event block.
+    per-event kernel path streams its lane-dense (L, 8, Np) loads through
+    HBM once per event; the blocked path touches its resident carry, whose
+    loads are (Np, 128) rows, once per T-event block.
 
     On a TPU the replay compiles with the native Pallas kernels, which
     appear in the HLO as opaque custom-calls - ``charge_custom_calls=True``
@@ -223,9 +224,10 @@ def replay_block_bytes(lanes: int = 2, n_items: int = 40, d: int = 3,
     plain HLO (no custom-calls; the flag is inert there), so the model
     counts the emulated kernel's slice/update traffic directly - a looser
     proxy, but the per-event-vs-blocked comparison is the same structural
-    question: how often does the carry cross the HBM boundary.  Middle
-    column: bytes per event step; derived: reduction factor vs per-event.
-    Asserts the blocked path moves strictly less."""
+    question: how often does the carry cross the HBM boundary, and how
+    wide is it.  Middle column: bytes per event step; derived: reduction
+    factor vs per-event.  Asserts the per-event path moves strictly less:
+    at T=8 the blocked path's 128-wide rows outweigh its amortization."""
     from functools import partial
 
     from repro.launch.hlo_cost import module_cost
@@ -247,8 +249,9 @@ def replay_block_bytes(lanes: int = 2, n_items: int = 40, d: int = 3,
 
     b_ev = bytes_per_step(0)
     b_blk = bytes_per_step(T)
-    assert b_blk < b_ev, \
-        f"blocked replay must move strictly fewer bytes: {b_blk} vs {b_ev}"
+    assert b_ev < b_blk, \
+        f"the lane-dense per-event replay must move strictly fewer bytes: " \
+        f"{b_ev} vs {b_blk}"
     tag = _interpret_tag()
     tag = f"  #{tag}" if tag else ""
     return [f"perf/replay_block_bytes_perevent,{b_ev:.0f},1.00{tag}",

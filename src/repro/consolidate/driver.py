@@ -53,9 +53,11 @@ def _segment(sizes, times, kinds, items, pdeps, dmask, arrivals, rdeps,
         ev_extra=ev_extra if ev_extra else None, migrate=migrate)
 
 
-def _pool_view(carry, d: int) -> Dict[str, np.ndarray]:
-    """Planner-facing float64 view of either replay carry layout: the
-    packed kernel dict (event-blocked path) or the jnp core tuple."""
+def _pool_view(carry, d: int, backend: str) -> Dict[str, np.ndarray]:
+    """Planner-facing float64 view of every replay carry layout: the
+    packed kernel dict (event-blocked path) or the core tuple, whose loads
+    are (L, slots, d) on the jnp backend and (L, dsub, Np) on the kernel
+    ones."""
     if isinstance(carry, dict):
         sloti = np.asarray(carry["sloti"])
         return {"loads": np.asarray(carry["loads"])[..., :d]
@@ -66,7 +68,10 @@ def _pool_view(carry, d: int) -> Dict[str, np.ndarray]:
                 "placements": np.asarray(carry["itemi"])
                 [..., _fk.ITEMI_PLACE]}
     core, _cat = carry
-    return {"loads": np.asarray(core[0])[..., :d].astype(np.float64),
+    loads = np.asarray(core[0])
+    if backend != "jnp":
+        loads = np.swapaxes(loads, 1, 2)
+    return {"loads": loads[..., :d].astype(np.float64),
             "counts": np.asarray(core[1]),
             "alive": np.asarray(core[2]),
             "open_seq": np.asarray(core[3]),
@@ -139,7 +144,7 @@ def consolidated_replay(sizes, times, kinds, items, pdeps, dmask,
                     last_t[lane] = times_np[lane, i]
             if e >= E:
                 break   # never plan after the final chunk
-            view = _pool_view(carry, d)
+            view = _pool_view(carry, d, backend)
             plans: List[List[int]] = []
             for lane in range(L):
                 run, t_next[lane] = should_plan(

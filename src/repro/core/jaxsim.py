@@ -33,11 +33,11 @@ elsewhere, override with REPRO_FITSCORE_BACKEND):
   * "pallas" / "pallas_interpret" - the decision is the fused
     ``kernels.fitscore.fitscore_select_batch_padded`` kernel (feasibility +
     policy score + category mask + opening-order tie-break + free-slot
-    selection in one VMEM-tiled pass, zero host round-trips per step).  The
-    whole carry lives in the kernel's padded (Np, dpad) layout - padded
-    once before the scan and unpadded never (outputs are per-lane scalars),
-    instead of re-padding the state every step (~25x redundant data traffic
-    at d=5).
+    selection in one pass over blocks of whole lanes, zero host
+    round-trips per step).  The carry lives in the kernel's lane-dense
+    layout (``kernels.fitscore.select_event_geometry``): loads (L, dsub,
+    Np) with the dims on sublanes (d rounded up to 8) and the slots on
+    lanes, laid out once before the scan (outputs are per-lane scalars).
 
     With ``block_events=T > 1`` the kernel backends go one rung further:
     the scan runs over *event blocks*, each block replayed entirely
@@ -82,7 +82,8 @@ from ..kernels.fitscore import (ARRIVAL_KIND, DEPARTURE_KIND, F32_EPS,
                                 TAG_BASE, TAG_GENERAL, TAG_LARGE, TAG_NONE,
                                 TAG_VIRGIN, fitscore_replay_block,
                                 fitscore_select_batch_padded,
-                                replay_carry_names, select_pad_geometry)
+                                replay_carry_names, select_event_geometry,
+                                select_pad_geometry)
 from ..kernels import fitscore as _fk
 from .. import obs
 from .algorithms.adaptive import pow2_ceiling_jnp, prediction_error_jnp
@@ -397,12 +398,15 @@ def _category_state0(spec, L, item_rows, d, Np):
     return {"err": jnp.ones((L,), f32)}
 
 
-def _core_state0(L, Np, dpad, item_rows):
+def _core_state0(loads_shape, Np, item_rows):
     """The fresh core scan carry (loads, counts, alive, open/access seq,
     closes, open_time, placements, usage, seq, opened, overflow) - exactly
-    what ``_replay_batch`` starts from when ``carry0`` is None."""
+    what ``_replay_batch`` starts from when ``carry0`` is None.  Loads are
+    (L, Np, d) on the jnp backend and (L, dsub, Np) on the kernel ones
+    (``select_event_geometry``)."""
     i32 = jnp.int32
-    return (jnp.zeros((L, Np, dpad)), jnp.zeros((L, Np), i32),
+    L = loads_shape[0]
+    return (jnp.zeros(loads_shape), jnp.zeros((L, Np), i32),
             jnp.zeros((L, Np), bool),
             jnp.zeros((L, Np), i32),
             jnp.full((L, Np), -1, i32),
@@ -536,7 +540,7 @@ def _replay_batch_blocked(sizes, times, kinds, items, pdeps, dmask,
     T = int(block_events)
     Np, dpad, _, _ = select_pad_geometry(max_bins, d)
 
-    # pad once, exactly as the per-event kernel path does
+    # pad once into the megakernel's (Np, dpad) layout
     sizes_p = jnp.asarray(sizes, f32) if dpad == d else \
         jnp.zeros((L, n_max, dpad), f32).at[:, :, :d].set(sizes)
     dm = jnp.ones((L, d), f32) if dmask is None else jnp.asarray(dmask, f32)
@@ -645,6 +649,21 @@ def packed_init_carry(fam: str, L: int, item_rows: int, max_bins: int,
     return carry
 
 
+def replay_loads_shape(L: int, max_bins: int, d: int, *, backend: str,
+                       block_events: int = 0):
+    """Shape of the slot loads ``_replay_batch`` carries: (L, max_bins, d)
+    on the jnp backend, (L, Np, dpad) on the event-blocked megakernel
+    (``select_pad_geometry``) and (L, dsub, Np) on the per-event kernel
+    (``select_event_geometry``)."""
+    if backend == "jnp":
+        return (L, max_bins, d)
+    if block_events and block_events > 1:
+        Np, dpad, _, _ = select_pad_geometry(max_bins, d)
+        return (L, Np, dpad)
+    Np, dsub = select_event_geometry(max_bins, d)
+    return (L, dsub, Np)
+
+
 def replay_init_carry(policy: str, max_bins: int, d: int, item_rows: int,
                       *, L: int = 1, backend: str = "jnp",
                       block_events: int = 0):
@@ -656,11 +675,9 @@ def replay_init_carry(policy: str, max_bins: int, d: int, item_rows: int,
     if backend != "jnp" and block_events and block_events > 1:
         return packed_init_carry(_KERNEL_FAMILY[spec.family], L, item_rows,
                                  max_bins, d)
-    if backend != "jnp":
-        Np, dpad, _, _ = select_pad_geometry(max_bins, d)
-    else:
-        Np, dpad = max_bins, d
-    return (_core_state0(L, Np, dpad, item_rows),
+    loads_shape = replay_loads_shape(L, max_bins, d, backend=backend)
+    Np = max_bins if backend == "jnp" else loads_shape[2]
+    return (_core_state0(loads_shape, Np, item_rows),
             _category_state0(spec, L, item_rows, d, Np))
 
 
@@ -756,7 +773,9 @@ def _replay_batch(sizes, times, kinds, items, pdeps, dmask, arrivals=None,
     ``backend="jnp"`` selects with the inline vmapped ``_select_slot`` on a
     compact (max_bins, d) carry; "pallas"/"pallas_interpret" run the kernel
     natively / in interpret mode with the carry held permanently in the
-    padded (Np, dpad) kernel layout (padded once here, not per step).
+    kernel's lane-dense layout (``select_event_geometry``: loads
+    (L, dsub, Np), dims on sublanes and slots on lanes), laid out once
+    here, not per step.
 
     Segmented (checkpointed) replay threads the scan carry through:
     ``carry0`` resumes from a prior segment's carry, ``return_carry``
@@ -788,19 +807,53 @@ def _replay_batch(sizes, times, kinds, items, pdeps, dmask, arrivals=None,
     spec = policy_spec(policy)
     L, n_max, d = sizes.shape
     f32, i32 = jnp.float32, jnp.int32
-    if kernel_layout:
-        Np, dpad, _, _ = select_pad_geometry(max_bins, d)
-    else:
-        Np, dpad = max_bins, d
     lanes = jnp.arange(L)
-
-    # pad once: item sizes and the dim mask live in the select's dpad
-    # layout for the whole scan
-    sizes_p = jnp.asarray(sizes, f32) if dpad == d else \
-        jnp.zeros((L, n_max, dpad), f32).at[:, :, :d].set(sizes)
     dm = jnp.ones((L, d), f32) if dmask is None else jnp.asarray(dmask, f32)
-    dmask_p = dm if dpad == d else \
-        jnp.zeros((L, dpad), f32).at[:, :d].set(dm)
+    loads_shape = replay_loads_shape(L, max_bins, d, backend=backend)
+    if kernel_layout:
+        # lay out once: loads (L, dsub, Np), item sizes (L, dsub, n_max) so
+        # one event's sizes gather to the kernel's (L, dsub, 1) operand
+        _, dsub, Np = loads_shape
+        sizes_p = jnp.zeros((L, dsub, n_max), f32).at[:, :d].set(
+            jnp.swapaxes(jnp.asarray(sizes, f32), 1, 2))
+        dmask_p = jnp.zeros((L, dsub, 1), f32).at[:, :d, 0].set(dm)
+        slot_ids = jnp.arange(Np, dtype=i32)
+
+        def event_size(j):
+            size = jnp.take_along_axis(sizes_p, j[:, None, None], axis=2)
+            return size, size[:, :d, 0]
+
+        def at_slot(b):           # (L, 1, Np): slot b of each lane
+            return (slot_ids == b[:, None])[:, None, :]
+
+        # one masked pass over the loads, not a scatter into a strided
+        # column
+        def loads_add(loads, b, v):
+            return jnp.where(at_slot(b), loads + v, loads)
+
+        def loads_clear(loads, b, cond):
+            return jnp.where(at_slot(b) & cond[:, None, None], 0.0, loads)
+
+        def loads_at(loads, b):
+            return jnp.take_along_axis(loads, b[:, None, None], axis=2)
+    else:
+        Np = max_bins
+        sizes_p = jnp.asarray(sizes, f32)
+        dmask_p = dm
+
+        def event_size(j):
+            size = jnp.take_along_axis(sizes_p, j[:, None, None], axis=1)
+            return size[:, 0], size[:, 0]
+
+        def loads_add(loads, b, v):
+            return loads.at[lanes, b].add(v)
+
+        def loads_clear(loads, b, cond):
+            return loads.at[lanes, b].set(
+                jnp.where(cond[:, None], 0.0, loads[lanes, b]))
+
+        def loads_at(loads, b):
+            return loads[lanes, b]
 
     consts, cat0, xs_extra = _category_setup(
         spec, sizes, pdeps, dmask, arrivals, rdeps, n_items, times, kinds,
@@ -833,24 +886,21 @@ def _replay_batch(sizes, times, kinds, items, pdeps, dmask, arrivals=None,
         t, kind = ev[0], ev[1]
         j = ev[2].astype(i32)
         g = lambda a: jnp.take_along_axis(a, j[:, None], axis=1)[:, 0]
-        size = jnp.take_along_axis(sizes_p, j[:, None, None], axis=1)[:, 0]
-        size_d = size[:, :d]
+        size, size_d = event_size(j)
         pdep_j = g(pdeps)
         is_arr = kind == ARRIVAL_KIND
         is_pad = kind == PAD_KIND
 
         # ---- departure branch: shared bin bookkeeping
         b_dep = g(placements)
-        loads_dep = loads.at[lanes, b_dep].add(-size)
         counts_dep = counts.at[lanes, b_dep].add(-1)
         closing = counts_dep[lanes, b_dep] == 0
         usage_dep = usage + jnp.where(closing, t - open_time[lanes, b_dep],
                                       0.0)
         alive_dep = alive.at[lanes, b_dep].set(
             jnp.where(closing, False, alive[lanes, b_dep]))
-        loads_dep = loads_dep.at[lanes, b_dep].set(
-            jnp.where(closing[:, None], jnp.zeros((L, dpad)),
-                      loads_dep[lanes, b_dep]))
+        loads_dep = loads_clear(loads_add(loads, b_dep, -size), b_dep,
+                                closing)
         closes_dep = closes.at[lanes, b_dep].set(
             jnp.where(closing, NEG, closes[lanes, b_dep]))
 
@@ -954,10 +1004,11 @@ def _replay_batch(sizes, times, kinds, items, pdeps, dmask, arrivals=None,
                 fits_gen = jnp.max(cat_s["agg_gen"][lanes, catj] + size_d,
                                    axis=1) <= thr + F32_EPS
                 has_base = cat_s["base"] >= 0
-                base_loads = loads_s[lanes, jnp.maximum(cat_s["base"], 0)]
+                base_loads = loads_at(loads_s, jnp.maximum(cat_s["base"], 0))
                 base_fits = jnp.where(
                     has_base,
-                    jnp.all(size <= FIT_CAP - base_loads, axis=1),
+                    jnp.all((size <= FIT_CAP - base_loads).reshape(L, -1),
+                            axis=1),
                     True)
                 if excl is not None:
                     # migrate off the base bin itself: the re-place must
@@ -1055,7 +1106,7 @@ def _replay_batch(sizes, times, kinds, items, pdeps, dmask, arrivals=None,
             # ---- arrival branch: shared bin bookkeeping
             b = b.astype(i32)
             overflow_arr = overflow_s | (~found & no_free)
-            loads_arr = loads_s.at[lanes, b].add(size)
+            loads_arr = loads_add(loads_s, b, size)
             counts_arr = counts_s.at[lanes, b].add(1)
             alive_arr = alive_s.at[lanes, b].set(True)
             open_seq_arr = open_seq_s.at[lanes, b].set(
@@ -1105,14 +1156,15 @@ def _replay_batch(sizes, times, kinds, items, pdeps, dmask, arrivals=None,
             if "tag" in cat_n else jnp.full((L,), -1, i32)
         ys = {"slot": ev_slot,
               "open_bins": core_n[2].sum(axis=1).astype(i32),
-              "load": core_n[0].sum(axis=1)[:, :d].astype(jnp.float32),
+              "load": core_n[0].sum(axis=2 if kernel_layout else 1)
+              [:, :d].astype(jnp.float32),
               "tag": jnp.where(ev_slot >= 0, tag_n, -1).astype(i32),
               "usage": core_n[8].astype(jnp.float32)}
         if trace_level >= 2:
             ys["alive"] = core_n[2]
         return carry, ys
 
-    core0 = _core_state0(L, Np, dpad, n_max)
+    core0 = _core_state0(loads_shape, Np, n_max)
     xs = tuple(jnp.swapaxes(a, 0, 1)
                for a in (times, kinds, items) + xs_extra)
     init = (core0, cat0) if carry0 is None else \

@@ -16,10 +16,10 @@ VMEM tiling.  Three kernels live here:
     The full fused placement decision for a *batch of lanes*, covering the
     complete 8-policy score family of ``core.jaxsim`` (``SELECT_POLICIES``):
     feasibility, policy score, oracle-consistent (score, open_seq)
-    lexicographic running argmin, the two-stage case-(a)/case-(b) select of
-    ``nrt_prioritized``, and first-free-slot selection - one VMEM-tiled pass
-    over a ``(lanes, bin-tiles)`` grid that emits the chosen slot per lane
-    plus ``found`` / ``no_free`` flags.
+    lexicographic argmin, the two-stage case-(a)/case-(b) select of
+    ``nrt_prioritized``, and first-free-slot selection - one pass over a
+    grid of blocks of whole lanes that emits the chosen slot per lane plus
+    ``found`` / ``no_free`` flags.
 
     The optional *category mask* operand (``cmask``, (L, N) int32; 1 =
     eligible slot) restricts feasibility to category-compatible slots -
@@ -29,11 +29,14 @@ VMEM tiling.  Three kernels live here:
     from the carried per-slot category tags.
 
     ``fitscore_select_batch_padded`` is the hot-loop entry: the same
-    decision for state already held in the kernel's padded (Np, dpad)
-    layout (``select_pad_geometry``).  ``core.jaxsim._replay_batch`` keeps
-    its whole scan carry in that layout and calls it once per event-scan
-    step, so a whole sweep batch replays with zero host round-trips AND
-    zero per-step re-padding (~25x redundant data traffic at d=5 before).
+    decision for state already held in the kernel's lane-dense layout
+    (``select_event_geometry``): loads (L, dsub, Np) with the d dims on
+    sublanes (rounded up to 8) and the slots on the 128-wide lane axis, the
+    per-slot columns (L, Np).  A 2048-slot lane is then 16 vregs, so one
+    block holds whole lanes and each lane's argmin finishes inside it.
+    ``core.jaxsim._replay_batch`` keeps its scan carry in that layout and
+    calls it once per event-scan step, so a whole sweep batch replays with
+    zero host round-trips and zero per-step re-layout.
 
 ``fitscore_replay_block`` (the event-blocked replay megakernel)
     The next rung: instead of launching the select once per event and
@@ -208,126 +211,108 @@ def fitscore(remaining, alive, item, open_seq=None, *, norm: str = "linf",
 # Fused batched placement-step kernel (all 8 jaxsim policies)
 # ======================================================================
 
-def _select_kernel(loads_ref, counts_ref, alive_ref, oseq_ref, aseq_ref,
-                   closes_ref, size_ref, dmask_ref, cmask_ref, pdep_ref,
-                   now_ref, out_ref, fbest, ibest, *, policy: str, bn: int,
-                   nb: int, n: int):
-    """One (lane, bin-tile) grid step of the fused placement decision.
+def _select_kernel(*refs, policy: str, n: int, has_cmask: bool):
+    """One block of ``Lb`` whole lanes of the fused placement decision.
 
-    Per-slot operands arrive as (1, 1, bn) blocks of (L, 1, Np) arrays and
-    size/dmask as (1, 1, dpad) blocks, so every block's last two dims are
-    (8, 128)-aligned or equal the array's - the TPU tiling rule at L > 1.
-    ``pdep_ref`` / ``now_ref`` are whole (L,) SMEM vectors.
+    ``loads_ref`` is an (Lb, dsub, Np) block: dims on sublanes, slots on
+    lanes.  The per-slot columns (counts, alive, open_seq, access_seq,
+    closes and the optional category mask) are (Lb, Np) blocks, size and
+    dmask (Lb, dsub, 1) and pdep/now (Lb, 1).  Feasibility and the
+    best-fit norms combine the ``dsub`` sublane rows of each lane; the
+    lexicographic (score, open_seq, row) argmin and the first-free-slot
+    search reduce over the slot (lane) axis.  Each lane's decision is
+    complete inside its block, so the (slot, found, no_free) triple goes
+    straight to the (Lb, 3) output block.
 
-    ``cmask_ref`` (1, bn) int32 is the *category mask*: 1 marks slots the
-    policy's category structure allows for this arrival (same-tag bins for
-    CBD/CBDT/Hybrid/RCP lanes, same-lifetime-class bins for Lifetime
-    Alignment; all-ones for the plain score policies).  It is folded into
-    feasibility before scoring, so a lane with no category-compatible
-    feasible bin reports ``found=False`` and falls through to the free-slot
-    stage - exactly the host classes' "open a new bin of my category"
-    contract.
-
-    SMEM scratch layout (running state for the current lane; grid iterates
-    tiles innermost so it is reset at tile 0 and emitted at tile nb-1):
-      fbest[0] best case-(a) score     fbest[1] best case-(b) score
-      ibest[0] case-(a) open_seq       ibest[1] case-(a) slot
-      ibest[2] case-(b) open_seq       ibest[3] case-(b) slot
-      ibest[4] first free slot
-    Case (b) is only maintained for ``nrt_prioritized`` (its strict
-    case-(a)-before-case-(b) two-stage select); every other policy uses the
-    case-(a) registers alone.
+    ``cmask_ref`` (present when ``has_cmask``) is the *category mask*: 1
+    marks slots the policy's category structure allows for this arrival
+    (same-tag bins for CBD/CBDT/Hybrid/RCP lanes, same-lifetime-class bins
+    for Lifetime Alignment).  It is folded into feasibility before
+    scoring, so a lane with no category-compatible feasible bin reports
+    ``found=False`` and falls through to the free-slot stage - exactly the
+    host classes' "open a new bin of my category" contract.
     """
-    b = pl.program_id(0)
-    i = pl.program_id(1)
-
-    @pl.when(i == 0)
-    def _init():
-        fbest[0] = jnp.float32(SCORE_BIG)
-        fbest[1] = jnp.float32(SCORE_BIG)
-        ibest[0] = jnp.int32(IBIG)
-        ibest[1] = jnp.int32(0)
-        ibest[2] = jnp.int32(IBIG)
-        ibest[3] = jnp.int32(0)
-        ibest[4] = jnp.int32(IBIG)
-
-    loads = loads_ref[...].astype(jnp.float32)    # (1, bn, dpad)
-    size = size_ref[0].astype(jnp.float32)        # (1, dpad)
-    dmask = dmask_ref[0].astype(jnp.float32)      # (1, dpad)
-    counts = counts_ref[0]                        # (1, bn) int32
-    oseq = oseq_ref[0]                            # (1, bn) int32
-    rows = i * bn + jax.lax.broadcasted_iota(jnp.int32, (1, bn), 1)
+    if has_cmask:
+        (loads_ref, counts_ref, alive_ref, oseq_ref, aseq_ref, closes_ref,
+         cmask_ref, size_ref, dmask_ref, pdep_ref, now_ref, out_ref) = refs
+    else:
+        (loads_ref, counts_ref, alive_ref, oseq_ref, aseq_ref, closes_ref,
+         size_ref, dmask_ref, pdep_ref, now_ref, out_ref) = refs
+    f32, i32 = jnp.float32, jnp.int32
+    Lb, dsub, Np = loads_ref.shape
+    rows = jax.lax.broadcasted_iota(i32, (Lb, Np), 1)
     rowmask = rows < n
-    alive = (alive_ref[0] > 0) & rowmask
-    pdep = pdep_ref[b]
-    now = now_ref[b]
+    oseq = oseq_ref[...]
+    pdep = pdep_ref[...]                          # (Lb, 1)
+    now = now_ref[...]
+    best_fit = policy.startswith("best_fit")
 
     # feasibility - the exact jnp expression of core.jaxsim._score,
-    # restricted to category-compatible slots
-    feasible = jnp.all(size[:, None, :] <= FIT_CAP - loads,
-                       axis=2) & alive & (cmask_ref[0] > 0)     # (1, bn)
+    # restricted to category-compatible slots - and the best-fit norms,
+    # one sublane row of every lane at a time
+    feasible = (alive_ref[...] > 0) & rowmask
+    if has_cmask:
+        feasible = feasible & (cmask_ref[...] > 0)
+    s = None
+    for k in range(dsub):
+        loads_k = loads_ref[:, k, :]              # (Lb, Np)
+        size_k = size_ref[:, k, :]                # (Lb, 1)
+        feasible = feasible & (size_k <= FIT_CAP - loads_k)
+        if best_fit:
+            after = 1.0 - loads_k - size_k
+            dm = dmask_ref[:, k, :]
+            if policy.endswith("linf"):
+                term = jnp.where(dm > 0, after, SCORE_NEG)
+                s = term if s is None else jnp.maximum(s, term)
+            else:
+                term = after * dm
+                if policy.endswith("l2"):
+                    term = term * term
+                s = term if s is None else s + term
 
     if policy == "first_fit":
-        s = oseq.astype(jnp.float32)
+        s = oseq.astype(f32)
     elif policy == "mru":
-        s = -aseq_ref[0].astype(jnp.float32)
-    elif policy.startswith("best_fit"):
-        after = 1.0 - loads - size[:, None, :]    # (1, bn, dpad)
-        if policy.endswith("l1"):
-            s = jnp.sum(after * dmask[:, None, :], axis=2)
-        elif policy.endswith("l2"):
-            masked = after * dmask[:, None, :]
-            s = jnp.sqrt(jnp.sum(masked * masked, axis=2))
-        else:
-            s = jnp.max(jnp.where(dmask[:, None, :] > 0, after, SCORE_NEG),
-                        axis=2)
+        s = -aseq_ref[...].astype(f32)
+    elif best_fit:
+        if policy.endswith("l2"):
+            s = jnp.sqrt(s)
     elif policy == "greedy":
-        s = -jnp.maximum(closes_ref[0], now)
+        s = -jnp.maximum(closes_ref[...], now)
     elif policy == "nrt_standard":
-        s = jnp.abs(jnp.maximum(closes_ref[0], now) - pdep)
-    else:   # nrt_prioritized
-        gap = jnp.maximum(closes_ref[0], now) - pdep
-        sa = jnp.where(feasible & (gap >= 0), gap, SCORE_BIG)
-        sb = jnp.where(feasible & (gap < 0), -gap, SCORE_BIG)
+        s = jnp.abs(jnp.maximum(closes_ref[...], now) - pdep)
+    else:   # nrt_prioritized: case (a) strictly before case (b)
+        gap = jnp.maximum(closes_ref[...], now) - pdep
 
-    def merge(score, f_slot: int, i_slot: int):
-        """(score, open_seq) lexicographic running argmin over tiles."""
-        tile_best = jnp.min(score)
-        tied_seq = jnp.where((score == tile_best) & feasible, oseq, IBIG)
-        tile_seq = jnp.min(tied_seq)
-        tile_arg = jnp.min(jnp.where(tied_seq == tile_seq, rows, IBIG))
-        better = (tile_best < fbest[f_slot]) | \
-            ((tile_best == fbest[f_slot]) & (tile_seq < ibest[i_slot]))
+    def lane_min(a):
+        return jnp.min(a, axis=1, keepdims=True)  # (Lb, 1)
 
-        @pl.when(better)
-        def _():
-            fbest[f_slot] = tile_best
-            ibest[i_slot] = tile_seq
-            ibest[i_slot + 1] = tile_arg
+    def argmin(score):
+        """(score, open_seq, row) lexicographic argmin of each lane: the
+        oracle walks open bins in opening order and keeps the first
+        minimum, so score ties fall to the earliest-opened bin - NOT the
+        smallest slot index (a closed slot reused later has a small index
+        but a late open_seq)."""
+        score = jnp.where(feasible, score, SCORE_BIG)
+        smin = lane_min(score)
+        tied = jnp.where((score == smin) & feasible, oseq, IBIG)
+        tseq = lane_min(tied)
+        return smin, lane_min(jnp.where(tied == tseq, rows, IBIG))
 
     if policy == "nrt_prioritized":
-        merge(sa, 0, 0)
-        merge(sb, 1, 2)
+        amin, arow = argmin(jnp.where(gap >= 0, gap, SCORE_BIG))
+        bmin, brow = argmin(jnp.where(gap < 0, -gap, SCORE_BIG))
+        found = (amin < SCORE_BIG) | (bmin < SCORE_BIG)
+        best = jnp.where(amin < SCORE_BIG, arow, brow)
     else:
-        merge(jnp.where(feasible, s, SCORE_BIG), 0, 0)
-
-    tile_free = jnp.min(jnp.where((counts == 0) & rowmask, rows, IBIG))
-    ibest[4] = jnp.minimum(ibest[4], tile_free)
-
-    @pl.when(i == nb - 1)
-    def _emit():
-        found_a = fbest[0] < SCORE_BIG
-        if policy == "nrt_prioritized":
-            found = found_a | (fbest[1] < SCORE_BIG)
-            best = jnp.where(found_a, ibest[1], ibest[3])
-        else:
-            found = found_a
-            best = ibest[1]
-        no_free = ibest[4] >= IBIG
-        free = jnp.where(no_free, 0, ibest[4])   # argmin-of-empty == 0 (jnp)
-        out_ref[b, 0] = jnp.where(found, best, free)
-        out_ref[b, 1] = found.astype(jnp.int32)
-        out_ref[b, 2] = no_free.astype(jnp.int32)
+        smin, best = argmin(s)
+        found = smin < SCORE_BIG
+    free = lane_min(jnp.where((counts_ref[...] == 0) & rowmask, rows, IBIG))
+    no_free = free >= IBIG
+    # argmin-of-empty == 0, as in the jnp twin
+    out_ref[:, 0:1] = jnp.where(found, best, jnp.where(no_free, 0, free))
+    out_ref[:, 1:2] = found.astype(i32)
+    out_ref[:, 2:3] = no_free.astype(i32)
 
 
 def select_pad_geometry(n: int, d: int, bn: int = 256):
@@ -339,53 +324,84 @@ def select_pad_geometry(n: int, d: int, bn: int = 256):
     return nb * bn_, dpad, bn_, nb
 
 
+# The per-event select's layout (``core.jaxsim._replay_batch`` carries its
+# loads in it).  A tag of it goes into checkpoint digests, so a snapshot
+# written in another layout is recomputed instead of resumed.
+SELECT_LAYOUT = "lanes,dsub,Np"
+SELECT_BLOCK_BYTES = 2 * 2 ** 20    # VMEM budget of one block's loads
+
+
+def select_event_geometry(n: int, d: int):
+    """The per-event select's layout for an ``n``-slot, ``d``-dim pool:
+    (Np, dsub).  Loads are (L, dsub, Np): dims on sublanes (d rounded up
+    to 8), slots on lanes (n rounded up to 128), so one lane of a
+    2048-slot pool is 16 vregs.  The event-blocked megakernel keeps its
+    own layout (``select_pad_geometry``)."""
+    return max(128, -(-n // 128) * 128), max(8, -(-d // 8) * 8)
+
+
+def select_lanes_per_block(L: int, Np: int, dsub: int) -> int:
+    """Lanes per block of the per-event select: all ``L`` when their loads
+    fit ``SELECT_BLOCK_BYTES``, else the largest multiple of 8 dividing L
+    that fits (the smallest such divisor when none fits, L when none
+    exists) - the (8, 128) tiling rule for the (L, Np) columns."""
+    lane = dsub * Np * 4
+    if L * lane <= SELECT_BLOCK_BYTES:
+        return L
+    divs = [b for b in range(8, L, 8) if L % b == 0]
+    fits = [b for b in divs if b * lane <= SELECT_BLOCK_BYTES]
+    return max(fits) if fits else (min(divs) if divs else L)
+
+
 def fitscore_select_batch_padded(loads, counts, alive, open_seq, access_seq,
                                  closes, size, pdep, now, dmask, cmask=None,
-                                 *, policy: str, n: int, bn: int = 256,
+                                 *, policy: str, n: int,
                                  interpret: bool = False):
-    """``fitscore_select_batch`` for state already in kernel layout.
+    """``fitscore_select_batch`` for state already in the per-event layout.
 
-    Arguments are pre-padded per :func:`select_pad_geometry`: loads
-    (L, Np, dpad); counts/alive/open_seq/access_seq/closes and the optional
-    category mask ``cmask`` (L, Np); size/dmask (L, dpad); pdep/now (L,).
-    ``n`` is the real slot-pool size (rows >= n are layout padding and are
-    excluded from both the feasible and the free-slot stage).
+    Arguments are laid out per :func:`select_event_geometry`: loads
+    (L, dsub, Np); counts/alive/open_seq/access_seq/closes and the optional
+    category mask ``cmask`` (L, Np); size/dmask (L, dsub, 1); pdep/now (L,)
+    or (L, 1).  ``n`` is the real slot-pool size (slots >= n are layout
+    padding and are excluded from both the feasible and the free-slot
+    stage).
 
-    This is the replay scan's entry: ``core.jaxsim._replay_batch`` keeps its
-    whole carry in this layout, so each step reads/writes the state the
-    kernel consumes directly instead of re-padding (Np x dpad) every event
-    (~25x redundant traffic at d=5).
+    This is the replay scan's entry: ``core.jaxsim._replay_batch`` keeps
+    its carry in this layout, so each step reads the state the kernel
+    consumes directly.  The grid runs over blocks of whole lanes
+    (:func:`select_lanes_per_block`).
     """
     assert policy in SELECT_POLICIES, policy
-    L, Np, dpad = loads.shape
-    Np_, dpad_, bn_, nb = select_pad_geometry(n, 1, bn)
-    assert Np == Np_ and dpad % 128 == 0, (loads.shape, n, bn)
+    L, dsub, Np = loads.shape
+    assert (Np, dsub) == select_event_geometry(n, dsub), (loads.shape, n)
     f32, i32 = jnp.float32, jnp.int32
-    if cmask is None:
-        cmask = jnp.ones((L, Np), i32)
-    kernel = functools.partial(_select_kernel, policy=policy, bn=bn_, nb=nb,
-                               n=n)
-    slot = pl.BlockSpec((1, 1, bn_), lambda b, i: (b, 0, i))
-    vec = pl.BlockSpec((1, 1, dpad), lambda b, i: (b, 0, 0))
-    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-
-    def row(a, dt):   # (L, X) -> (L, 1, X): unit middle axis for tiling
-        return a.astype(dt).reshape(L, 1, a.shape[-1])
-
+    Lb = select_lanes_per_block(L, Np, dsub)
+    cols = [counts.astype(i32), alive.astype(i32), open_seq.astype(i32),
+            access_seq.astype(i32), closes.astype(f32)]
+    if cmask is not None:
+        cols.append(cmask.astype(i32))
+    col = pl.BlockSpec((Lb, Np), lambda b: (b, 0))
+    vec = pl.BlockSpec((Lb, dsub, 1), lambda b: (b, 0, 0))
+    lane = pl.BlockSpec((Lb, 1), lambda b: (b, 0))
+    block = _vmem_tile_bytes((dsub, Np)) * Lb + \
+        len(cols) * _vmem_tile_bytes((Lb, Np))
+    kernel = functools.partial(_select_kernel, policy=policy, n=n,
+                               has_cmask=cmask is not None)
     out = pl.pallas_call(
         kernel,
-        grid=(L, nb),
-        in_specs=[pl.BlockSpec((1, bn_, dpad), lambda b, i: (b, i, 0)),
-                  slot, slot, slot, slot, slot, vec, vec, slot, smem, smem],
-        out_specs=smem,
-        out_shape=jax.ShapeDtypeStruct((L, 3), jnp.int32),
-        scratch_shapes=[pltpu.SMEM((2,), jnp.float32),
-                        pltpu.SMEM((8,), jnp.int32)],
+        grid=(L // Lb,),
+        in_specs=[pl.BlockSpec((Lb, dsub, Np), lambda b: (b, 0, 0))] +
+        [col] * len(cols) + [vec, vec, lane, lane],
+        out_specs=pl.BlockSpec((Lb, 3), lambda b: (b, 0)),
+        out_shape=jax.ShapeDtypeStruct((L, 3), i32),
+        # the input blocks double-buffered, as much again for the
+        # kernel's (Lb, Np) temporaries
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=max(4 * block + VMEM_HEADROOM_BYTES,
+                                 VMEM_DEFAULT_BYTES)),
         interpret=interpret,
-    )(loads.astype(f32), row(counts, i32), row(alive, i32),
-      row(open_seq, i32), row(access_seq, i32), row(closes, f32),
-      row(size, f32), row(dmask, f32), row(cmask, i32),
-      pdep.astype(f32).reshape(L), now.astype(f32).reshape(L))
+    )(loads.astype(f32), *cols, size.astype(f32), dmask.astype(f32),
+      pdep.astype(f32).reshape(L, 1), now.astype(f32).reshape(L, 1))
     return out[:, 0], out[:, 1] > 0, out[:, 2] > 0
 
 
@@ -539,8 +555,8 @@ def _replay_block_kernel(*refs, family: str, policy: str, n: int, d: int,
             source bin - from feasibility, never from the free-slot stage.
 
             Deliberately a third expression of the shared scoring
-            semantics (per-lane (Np, 1) columns here vs the tiled
-            (1, bn) SMEM-register select kernel): the three stay pinned
+            semantics (per-lane (Np, 1) columns here vs the (Lb, Np)
+            lane rows of the per-event select kernel): the three stay pinned
             together by the shared SCORE_*/FIT_CAP/IBIG constants and the
             bitwise parity matrix in tests/test_fitscore_select.py +
             tests/test_replay_block.py - any drift fails those, so edit
@@ -1024,7 +1040,7 @@ def fitscore_replay_chunk(carry, ev_i, ev_f, ev_size, dmask, *,
 
 def fitscore_select_batch(loads, counts, alive, open_seq, access_seq, closes,
                           size, pdep, now, dmask, cmask=None, *, policy: str,
-                          bn: int = 256, interpret: bool = False):
+                          interpret: bool = False):
     """Fused batched DVBP placement step over ``L`` independent lanes.
 
     loads: (L, N, d) per-slot load vectors; counts/alive/open_seq/access_seq/
@@ -1036,25 +1052,24 @@ def fitscore_select_batch(loads, counts, alive, open_seq, access_seq, closes,
     Returns ``(slot, found, no_free)``, each ``(L,)`` - the slot the policy
     places into (the best feasible bin, else the first free slot, else slot
     0 with ``no_free`` set), matching ``core.jaxsim._select_slot`` decision
-    -for-decision.  Pads the state into kernel layout on every call; hot
-    loops should hold their state pre-padded and call
+    -for-decision.  Lays the state out for the kernel on every call; hot
+    loops should hold their state in that layout and call
     :func:`fitscore_select_batch_padded` instead.
     """
     L, N, d = loads.shape
-    Np, dpad, bn_, nb = select_pad_geometry(N, d, bn)
+    Np, dsub = select_event_geometry(N, d)
     f32, i32 = jnp.float32, jnp.int32
-    loads_p = jnp.zeros((L, Np, dpad), f32).at[:, :N, :d].set(
-        loads.astype(f32))
-    counts_p = jnp.zeros((L, Np), i32).at[:, :N].set(counts.astype(i32))
-    alive_p = jnp.zeros((L, Np), i32).at[:, :N].set(alive.astype(i32))
-    oseq_p = jnp.zeros((L, Np), i32).at[:, :N].set(open_seq.astype(i32))
-    aseq_p = jnp.zeros((L, Np), i32).at[:, :N].set(access_seq.astype(i32))
-    closes_p = jnp.zeros((L, Np), f32).at[:, :N].set(closes.astype(f32))
-    size_p = jnp.zeros((L, dpad), f32).at[:, :d].set(size.astype(f32))
-    dmask_p = jnp.zeros((L, dpad), f32).at[:, :d].set(dmask.astype(f32))
-    cmask_p = None if cmask is None else \
-        jnp.zeros((L, Np), i32).at[:, :N].set(cmask.astype(i32))
+
+    def col(a, dt):
+        return jnp.zeros((L, Np), dt).at[:, :N].set(a.astype(dt))
+
+    def vec(a):
+        return jnp.zeros((L, dsub, 1), f32).at[:, :d, 0].set(a.astype(f32))
+
+    loads_p = jnp.zeros((L, dsub, Np), f32).at[:, :d, :N].set(
+        jnp.swapaxes(loads.astype(f32), 1, 2))
     return fitscore_select_batch_padded(
-        loads_p, counts_p, alive_p, oseq_p, aseq_p, closes_p, size_p,
-        pdep, now, dmask_p, cmask_p, policy=policy, n=N, bn=bn,
-        interpret=interpret)
+        loads_p, col(counts, i32), col(alive, i32), col(open_seq, i32),
+        col(access_seq, i32), col(closes, f32), vec(size), pdep, now,
+        vec(dmask), None if cmask is None else col(cmask, i32),
+        policy=policy, n=N, interpret=interpret)

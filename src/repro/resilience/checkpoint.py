@@ -43,6 +43,7 @@ import jax
 import numpy as np
 
 from .. import obs
+from ..kernels.fitscore import SELECT_LAYOUT
 from . import faults
 
 # ------------------------------------------------- pytree (de)serialization
@@ -181,8 +182,10 @@ def _segment(sizes, times, kinds, items, pdeps, dmask, arrivals, rdeps,
 def _input_digest(arrays, policy, max_bins, backend, block_events,
                   seg: int, migrate: bool = False) -> str:
     h = hashlib.blake2b(digest_size=8)
+    # the layout tag: a snapshot of a carry in another per-event layout is
+    # stale, recomputed instead of resumed
     h.update(f"{policy}|{max_bins}|{backend}|{block_events}|{seg}"
-             f"|mig{int(migrate)}".encode())
+             f"|mig{int(migrate)}|{SELECT_LAYOUT}".encode())
     for a in arrays:
         if a is None:
             h.update(b"|none")
@@ -296,7 +299,7 @@ class StreamCheckpointer:
             backend: str, block_events: int, chunk_events: int) -> str:
         h = hashlib.blake2b(digest_size=8)
         h.update(f"{fingerprint}|{policy}|{max_bins}|{backend}"
-                 f"|{block_events}|{chunk_events}".encode())
+                 f"|{block_events}|{chunk_events}|{SELECT_LAYOUT}".encode())
         return f"{policy}-{h.hexdigest()}"
 
     def path_for(self, key: str) -> str:

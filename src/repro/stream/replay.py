@@ -20,7 +20,8 @@ synchronous reference (fence after every chunk), kept for the
 ``perf/stream_prefetch`` comparison.
 
 Recorded spans mark the staging boundaries: ``stream.init`` (builder,
-carry and pool of a pass), ``stream.build`` (one chunk's heap merge and
+carry and pool of a pass; ``loads_bytes`` is the carried slot loads'
+size, which names the layout), ``stream.build`` (one chunk's heap merge and
 build), ``stream.put`` (its transfer), ``stream.step`` (its dispatch) and
 ``stream.fence`` (waiting on the device); ``stream.replay`` carries the
 events and chunks replayed.  One span per chunk, never one per event.
@@ -34,6 +35,7 @@ pool (sources are re-iterable factories).
 from __future__ import annotations
 
 import dataclasses
+import math
 from collections import deque
 from functools import partial
 from typing import Optional
@@ -45,7 +47,8 @@ import numpy as np
 from .. import obs
 from ..core.jaxsim import (CapacityError, MAX_BINS_CAP, _replay_batch,
                            grow_live_items, grow_max_bins, policy_spec,
-                           replay_init_carry, resolve_backend)
+                           replay_init_carry, replay_loads_shape,
+                           resolve_backend)
 from ..kernels import fitscore as _fk
 from .events import ChunkedWorkload, InstanceSource, chunk_instance_events
 
@@ -167,6 +170,8 @@ def _replay_once(source, policy, *, chunk_events, item_rows, max_bins,
         sp.set(item_rows=rows)
         carry = replay_init_carry(policy, max_bins, d, rows, L=1,
                                   backend=backend, block_events=block_events)
+        sp.set(loads_bytes=4 * math.prod(replay_loads_shape(
+            1, max_bins, d, backend=backend, block_events=block_events)))
         pool = _pool_full(source) if wl.identity else _pool0(rows, d)
     gen = wl.chunks()
 

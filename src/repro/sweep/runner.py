@@ -46,6 +46,7 @@ near the expected peak open-bin count avoids the ladder entirely.
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import Dict, Optional, Sequence
 
@@ -56,7 +57,8 @@ import numpy as np
 from .. import obs
 from ..consolidate import ConsolidationSpec, consolidated_replay
 from ..core.jaxsim import (MAX_BINS_CAP, _replay_batch, grow_max_bins,
-                           known_policy, resolve_backend)
+                           known_policy, replay_loads_shape,
+                           resolve_backend)
 from ..obs.trace import ReplayTrace, from_scan
 from ..resilience import faults, guard
 from ..resilience.checkpoint import ReplayCheckpointer, checkpointed_replay
@@ -351,6 +353,10 @@ def run_batch(batch: InstanceBatch, policy: str,
     events = 0
     with obs.span("sweep.run_batch", policy=policy, backend=backend,
                   B=B, S=S) as rb_span:
+        # which loads layout the first scan carries, at what size
+        rb_span.set(loads_bytes=4 * math.prod(replay_loads_shape(
+            B * S, max_bins, batch.sizes.shape[2], backend=backend,
+            block_events=0 if trace_level else block_events)))
         rungs = 0
         while True:
             events += 2 * int(batch.n_items[lanes].sum(dtype=np.int64)) * S

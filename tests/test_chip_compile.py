@@ -45,13 +45,15 @@ def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _compile_select(sharding, L, n, policy):
-    Np, dpad, _, _ = fk.select_pad_geometry(n, D)
-    args = ([_spec((L, Np, dpad), F32, sharding)] +
+def _compile_select(sharding, L, n, policy, cmask=True):
+    Np, dsub = fk.select_event_geometry(n, D)
+    args = ([_spec((L, dsub, Np), F32, sharding)] +
             [_spec((L, Np), I32, sharding)] * 4 +
-            [_spec((L, Np), F32, sharding), _spec((L, dpad), F32, sharding),
+            [_spec((L, Np), F32, sharding),
+             _spec((L, dsub, 1), F32, sharding),
              _spec((L,), F32, sharding), _spec((L,), F32, sharding),
-             _spec((L, dpad), F32, sharding), _spec((L, Np), I32, sharding)])
+             _spec((L, dsub, 1), F32, sharding)] +
+            ([_spec((L, Np), I32, sharding)] if cmask else []))
     fn = jax.jit(lambda *a: fk.fitscore_select_batch_padded(
         *a, policy=policy, n=n))
     return fn.lower(*args).compile()
@@ -78,6 +80,19 @@ def test_select_compiles_for_v5e(one_chip, L, policy):
     """The per-event select with more than one lane: the default sweep path
     on TPU (``block_events=0``)."""
     compiled = _compile_select(one_chip, L, 256, policy)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("L,n", [(56, 2048), (1, 512), (28, 2048),
+                                 (1, 65536)])
+def test_select_compiles_at_cell_geometries(one_chip, L, n):
+    """The per-event select at the sweep cell's (56 lanes, 2048 slots) and
+    the stream cell's (1, 512) geometries, one block of 28 lanes, and one
+    lane at the 65,536-slot cap (2 MiB of loads: the block budget's
+    edge)."""
+    Np, dsub = fk.select_event_geometry(n, D)
+    assert fk.select_lanes_per_block(L, Np, dsub) == (8 if L == 56 else L)
+    compiled = _compile_select(one_chip, L, n, "best_fit_linf", cmask=False)
     assert "tpu_custom_call" in compiled.as_text()
 
 

@@ -13,12 +13,15 @@ is reused.
 """
 import subprocess
 import sys
+from functools import partial
 
+import jax
 import numpy as np
 import pytest
 
 from repro.core import Instance, get_algorithm, run
-from repro.core.jaxsim import POLICIES, simulate
+from repro.core.jaxsim import POLICIES, _select_slot, simulate
+from repro.kernels import fitscore as fk
 from repro.sweep import pack_instances, pad_predictions, run_batch
 
 
@@ -83,6 +86,75 @@ def test_simulate_kernel_backend_placements(mixed):
                      backend="pallas_interpret")
         assert (a.placements == b.placements).all(), policy
         assert a.usage_time == b.usage_time
+
+
+def select_state(seed, L, d, n, with_cmask):
+    """One select's state for ``L`` lanes of ``n`` slots: load rows drawn
+    from four per lane (best-fit score ties), open_seq a permutation halved
+    (open_seq ties) so opening order disagrees with the slot index,
+    access_seq / closes / pdep / now on coarse grids (mru, greedy and nrt
+    ties, both gap signs), some lanes with fewer real dims, and the last
+    lane full (no feasible slot, no free slot)."""
+    rng = np.random.default_rng(seed)
+    rows = rng.integers(0, 48, (L, 4, d)) / 64.0
+    loads = np.take_along_axis(rows, rng.integers(0, 4, (L, n))[:, :, None],
+                               axis=1)
+    counts = rng.integers(0, 3, (L, n))
+    oseq = np.stack([rng.permutation(n) // 2 for _ in range(L)])
+    aseq = rng.integers(0, n // 4 + 1, (L, n))
+    closes = rng.integers(0, 8, (L, n)) * 100.0
+    now = rng.integers(0, 8, L) * 100.0
+    pdep = now + rng.integers(-2, 8, L) * 100.0
+    size = rng.integers(0, 16, (L, d)) / 64.0
+    dmask = np.ones((L, d))
+    for lane in range(L):
+        real = rng.integers(1, d + 1)
+        dmask[lane, real:] = 0.0
+        size[lane, real:] = 0.0
+        loads[lane, :, real:] = 0.0
+    if L > 1:
+        counts[-1] = 1
+        loads[-1] = 63 / 64
+        size[-1, 0] = 2 / 64
+    cmask = rng.random((L, n)) > 0.3 if with_cmask else None
+    return (loads, counts, counts > 0, oseq, aseq, closes, size, pdep, now,
+            dmask, cmask)
+
+
+@pytest.mark.parametrize("L,d,n", [(1, 1, 20), (3, 2, 300), (8, 5, 512),
+                                   (13, 8, 300), (16, 5, 8192)])
+@pytest.mark.parametrize("with_cmask", [False, True])
+@pytest.mark.parametrize("policy", POLICIES)
+def test_select_kernel_matches_select_slot(policy, with_cmask, L, d, n):
+    """The lane-dense select kernel against the jnp twin, decision for
+    decision: lane counts that are not multiples of 8, pools padded to the
+    128-slot layout, d padded to 8 sublanes, and (16 lanes of 8192 slots)
+    a grid of two lane blocks."""
+    state = select_state(L * 1000 + d * 10 + n, L, d, n, with_cmask)
+    want = jax.vmap(partial(_select_slot, policy))(
+        *[None if a is None else jax.numpy.asarray(a, dt) for a, dt in
+          zip(state, ("float32", "int32", "bool", "int32", "int32",
+                      "float32", "float32", "float32", "float32", "float32",
+                      "bool"))])
+    got = fk.fitscore_select_batch(*state, policy=policy, interpret=True)
+    for w, g, what in zip(want, got, ("slot", "found", "no_free")):
+        assert (np.asarray(w) == np.asarray(g)).all(), (what, w, g)
+    if L > 1:
+        assert not got[1][-1] and got[2][-1] and got[0][-1] == 0
+
+
+def test_select_lanes_per_block():
+    """Whole lanes per block: every lane when their loads fit the 2 MiB
+    budget, else the largest fitting multiple of 8 that divides L."""
+    assert fk.select_event_geometry(2048, 5) == (2048, 8)
+    assert fk.select_event_geometry(300, 9) == (384, 16)
+    assert fk.select_lanes_per_block(28, 2048, 8) == 28
+    assert fk.select_lanes_per_block(56, 2048, 8) == 8
+    assert fk.select_lanes_per_block(64, 1024, 8) == 64
+    assert fk.select_lanes_per_block(96, 1024, 8) == 48
+    assert fk.select_lanes_per_block(1, 65536, 8) == 1
+    assert fk.select_lanes_per_block(16, 65536, 8) == 8
+    assert fk.select_lanes_per_block(13, 65536, 8) == 13
 
 
 def tie_break_instance():

@@ -161,6 +161,24 @@ def test_unrecorded_span_opens_no_annotation(monkeypatch):
     assert opened == ["test.on"]
 
 
+@pytest.mark.parametrize("backend,block_events,loads_bytes", [
+    ("jnp", 0, 2 * 64 * 3 * 4),                  # (L, max_bins, d)
+    ("pallas_interpret", 0, 2 * 8 * 128 * 4),    # (L, dsub, Np)
+    ("pallas_interpret", 8, 2 * 64 * 128 * 4)])  # (L, Np, dpad)
+def test_run_batch_span_names_the_loads_layout(backend, block_events,
+                                               loads_bytes):
+    """``sweep.run_batch`` carries the bytes of the slot loads its scan
+    carries, which differ by layout: compact on jnp, lane-dense on the
+    per-event kernel, 128-wide rows on the event-blocked one."""
+    batch = pack_instances([quantized_instance(s, 20, 3) for s in (1, 2)])
+    with obs.recording():
+        run_batch(batch, "first_fit", max_bins=64, backend=backend,
+                  block_events=block_events)
+        evs = obs.events()
+    rb, = [e for e in evs if e["name"] == "sweep.run_batch"]
+    assert rb["args"]["loads_bytes"] == loads_bytes
+
+
 def test_timeit_stats_and_row():
     import os
     import sys

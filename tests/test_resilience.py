@@ -246,6 +246,40 @@ def test_interrupt_resume_bit_identical(small_batch, tmp_path):
     assert np.array_equal(res.n_bins_opened, base.n_bins_opened)
 
 
+def test_snapshot_in_old_select_layout_recomputed(small_batch, tmp_path,
+                                                  monkeypatch):
+    """A snapshot whose carry holds the per-event loads in another layout
+    (the earlier (L, Np, 128) rows) is stale under the layout tag in the
+    digest: the replay starts over and gives the fault-free result,
+    instead of feeding the old carry to the scan."""
+    from repro.kernels.fitscore import select_pad_geometry
+    kw = dict(max_bins=64, backend="pallas_interpret", shard="never")
+    base = run_batch(small_batch, "greedy", **kw)
+    ckpt = ReplayCheckpointer(str(tmp_path), every_events=16)
+    monkeypatch.setattr(checkpoint, "SELECT_LAYOUT", "lanes,Np,dpad")
+    with faults.injected("ckpt.segment:error:3"):
+        with pytest.raises(faults.InjectedFault):
+            run_batch(small_batch, "greedy", checkpoint=ckpt,
+                      checkpoint_key="k", **kw)
+    monkeypatch.undo()
+    [name] = [f for f in os.listdir(tmp_path) if f.endswith(".npz")]
+    path = str(tmp_path / name)
+    (core, cat), meta = checkpoint.load_checkpoint(path)
+    L, dsub, _ = core[0].shape
+    Np, dpad, _, _ = select_pad_geometry(64, small_batch.sizes.shape[2])
+    rows = np.zeros((L, Np, dpad), np.float32)
+    rows[:, :, :dsub] = np.swapaxes(core[0], 1, 2)[:, :Np]
+    checkpoint.save_checkpoint(path, ((rows,) + tuple(core[1:]), cat), meta)
+    r0 = obs.counter_get("resilience.ckpt_resume")
+    s0 = obs.counter_get("resilience.ckpt_stale")
+    res = run_batch(small_batch, "greedy", checkpoint=ckpt,
+                    checkpoint_key="k", **kw)
+    assert obs.counter_get("resilience.ckpt_resume") == r0
+    assert obs.counter_get("resilience.ckpt_stale") == s0 + 1
+    assert np.array_equal(res.usage_time, base.usage_time)
+    assert np.array_equal(res.n_bins_opened, base.n_bins_opened)
+
+
 def _migrate_stream(n=24, every=8):
     """A flattened single-lane event stream with MIGRATE events spliced
     across checkpoint-segment boundaries: each picks an item alive at its

@@ -183,6 +183,8 @@ def test_recorded_spans_per_chunk(prefetch):
     names = [e["name"] for e in evs]
     assert res.n_chunks > 1
     assert names.count("stream.init") == 1
+    init, = [e for e in evs if e["name"] == "stream.init"]
+    assert init["args"]["loads_bytes"] == 64 * inst.sizes.shape[1] * 4
     for name in ("stream.build", "stream.put", "stream.step"):
         assert names.count(name) == res.n_chunks, name
     assert names.count("stream.fence") == (
