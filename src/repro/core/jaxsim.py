@@ -67,7 +67,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
-from functools import partial
+from functools import lru_cache, partial
 from typing import Optional
 
 import jax
@@ -560,8 +560,7 @@ def _replay_batch_blocked(sizes, times, kinds, items, pdeps, dmask,
     # with PAD_KIND no-ops (the tail block)
     items_i = jnp.asarray(items, i32)
     E = times.shape[1]
-    NB = -(-E // T)
-    pad = NB * T - E
+    pad = replay_scan_steps(E, backend=backend, block_events=T) - E
 
     def padded(a, fill):
         if pad == 0:
@@ -664,6 +663,18 @@ def replay_loads_shape(L: int, max_bins: int, d: int, *, backend: str,
     return (L, dsub, Np)
 
 
+def replay_scan_steps(E: int, *, backend: str, block_events: int = 0,
+                      trace_level: int = 0) -> int:
+    """Length of the event axis ``_replay_batch`` scans for ``E`` events,
+    padding included: ``E`` on the per-event paths, ``E`` rounded up to
+    whole blocks on the event-blocked megakernel (kernel backends,
+    ``block_events`` > 1, untraced)."""
+    if backend != "jnp" and block_events and block_events > 1 and \
+            not trace_level:
+        return -(-E // block_events) * block_events
+    return E
+
+
 def replay_init_carry(policy: str, max_bins: int, d: int, item_rows: int,
                       *, L: int = 1, backend: str = "jnp",
                       block_events: int = 0):
@@ -679,6 +690,23 @@ def replay_init_carry(policy: str, max_bins: int, d: int, item_rows: int,
     Np = max_bins if backend == "jnp" else loads_shape[2]
     return (_core_state0(loads_shape, Np, item_rows),
             _category_state0(spec, L, item_rows, d, Np))
+
+
+@lru_cache(maxsize=None)
+def replay_category_bytes(policy: str, max_bins: int, d: int,
+                          item_rows: int, *, L: int = 1,
+                          backend: str = "jnp", block_events: int = 0) -> int:
+    """Bytes that ``policy``'s family adds to the fresh carry of
+    ``replay_init_carry`` over the score family's, in the same layout: the
+    category state (hybrid: ``tag``, ``agg``, ``ingen`` on the per-event
+    paths, ``hagg`` on the event-blocked one); 0 for the score family."""
+    def nbytes(p):
+        carry = jax.eval_shape(partial(
+            replay_init_carry, p, max_bins, d, item_rows, L=L,
+            backend=backend, block_events=block_events))
+        return sum(x.size * x.dtype.itemsize
+                   for x in jax.tree_util.tree_leaves(carry))
+    return nbytes(policy) - nbytes("first_fit")
 
 
 def make_live_carry(policy: str, max_bins: int, d: int,

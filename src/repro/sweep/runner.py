@@ -57,7 +57,8 @@ import numpy as np
 from .. import obs
 from ..consolidate import ConsolidationSpec, consolidated_replay
 from ..core.jaxsim import (MAX_BINS_CAP, _replay_batch, grow_max_bins,
-                           known_policy, replay_loads_shape,
+                           known_policy, replay_category_bytes,
+                           replay_loads_shape, replay_scan_steps,
                            resolve_backend)
 from ..obs.trace import ReplayTrace, from_scan
 from ..resilience import faults, guard
@@ -353,10 +354,15 @@ def run_batch(batch: InstanceBatch, policy: str,
     events = 0
     with obs.span("sweep.run_batch", policy=policy, backend=backend,
                   B=B, S=S) as rb_span:
-        # which loads layout the first scan carries, at what size
+        # which loads layout and category state the first scan carries,
+        # at what size
+        _, n_max, d = batch.sizes.shape
+        blocked = 0 if trace_level else block_events
         rb_span.set(loads_bytes=4 * math.prod(replay_loads_shape(
-            B * S, max_bins, batch.sizes.shape[2], backend=backend,
-            block_events=0 if trace_level else block_events)))
+            B * S, max_bins, d, backend=backend, block_events=blocked)),
+            category_bytes=replay_category_bytes(
+                policy, max_bins, d, n_max, L=B * S, backend=backend,
+                block_events=blocked))
         rungs = 0
         while True:
             events += 2 * int(batch.n_items[lanes].sum(dtype=np.int64)) * S
@@ -366,7 +372,11 @@ def run_batch(batch: InstanceBatch, policy: str,
                             sum(int(x.nbytes) for x in sub))
             c0 = _jit_cache_entries()
             with obs.span("sweep.scan", policy=policy, max_bins=mb,
-                          lanes=int(lanes.size) * S) as sc:
+                          lanes=int(lanes.size) * S,
+                          steps=replay_scan_steps(
+                              batch.times.shape[1], backend=backend,
+                              block_events=block_events,
+                              trace_level=trace_level)) as sc:
                 if consolidate is not None:
                     u, o, ov, churn = _run_consolidated(
                         sub, policy=policy, max_bins=mb, backend=backend,

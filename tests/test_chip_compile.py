@@ -45,8 +45,8 @@ def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _compile_select(sharding, L, n, policy, cmask=True):
-    Np, dsub = fk.select_event_geometry(n, D)
+def _compile_select(sharding, L, n, policy, cmask=True, d=D):
+    Np, dsub = fk.select_event_geometry(n, d)
     args = ([_spec((L, dsub, Np), F32, sharding)] +
             [_spec((L, Np), I32, sharding)] * 4 +
             [_spec((L, Np), F32, sharding),
@@ -93,6 +93,20 @@ def test_select_compiles_at_cell_geometries(one_chip, L, n):
     Np, dsub = fk.select_event_geometry(n, D)
     assert fk.select_lanes_per_block(L, Np, dsub) == (8 if L == 56 else L)
     compiled = _compile_select(one_chip, L, n, "best_fit_linf", cmask=False)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_select_compiles_at_hybrid_cell_geometry(one_chip):
+    """The select of the Huawei-like hybrid fleets
+    (``bench/configs/huawei_east1.json``, ``sweep.hybrid``): 18 lanes (not
+    a multiple of 8) in one block, a 64-slot pool (half a lane tile), d = 2 (six of the
+    eight sublanes padding), first fit within the item's category
+    (``cmask``)."""
+    Np, dsub = fk.select_event_geometry(64, 2)
+    assert (Np, dsub) == (128, 8)
+    assert fk.select_lanes_per_block(18, 128, 8) == 18
+    compiled = _compile_select(one_chip, 18, 64, "first_fit", cmask=True,
+                               d=2)
     assert "tpu_custom_call" in compiled.as_text()
 
 

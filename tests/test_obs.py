@@ -179,6 +179,39 @@ def test_run_batch_span_names_the_loads_layout(backend, block_events,
     assert rb["args"]["loads_bytes"] == loads_bytes
 
 
+# category_bytes from the shapes of the layout that runs (L = 2 lanes,
+# 20 item rows, d = 3, a 2-slot pool): tag (L, Np) int32 (Np 2 on jnp, 128
+# on the per-event kernel), agg (L, 20, 3) f32, ingen (L, 20) bool on the
+# per-event paths; hagg (L, 20, 128) f32 on the event-blocked one; nothing
+# for the score family.  steps: the 40 events of 20 items, padded to whole
+# 16-event blocks (48) on the event-blocked path
+@pytest.mark.parametrize("policy,backend,block_events,category_bytes,steps", [
+    ("hybrid", "jnp", 0, 2 * 2 * 4 + 2 * 20 * 3 * 4 + 2 * 20, 40),
+    ("hybrid", "pallas_interpret", 0, 2 * 128 * 4 + 2 * 20 * 3 * 4 + 2 * 20,
+     40),
+    ("hybrid", "pallas_interpret", 16, 2 * 20 * 128 * 4, 48),
+    ("best_fit_linf", "pallas_interpret", 0, 0, 40)])
+def test_run_batch_span_counts_category_state(policy, backend, block_events,
+                                              category_bytes, steps):
+    """``sweep.run_batch`` carries the bytes of the category state its
+    first scan carries, counted before hybrid's 2-slot pool overflows and
+    grows; each ``sweep.scan`` (one per rung) carries the padded event
+    axis it scanned."""
+    batch = pack_instances([quantized_instance(s, 20, 3) for s in (1, 2)])
+    with obs.recording():
+        run_batch(batch, policy, max_bins=2, backend=backend,
+                  block_events=block_events)
+        evs = obs.events()
+    rb, = [e for e in evs if e["name"] == "sweep.run_batch"]
+    assert rb["args"]["category_bytes"] == category_bytes
+    scans = [e for e in evs if e["name"] == "sweep.scan"]
+    rungs = rb["args"].get("overflow_rungs", 0)
+    assert len(scans) == rungs + 1
+    assert sum(e["args"]["steps"] for e in scans) == steps * (rungs + 1)
+    if policy == "hybrid":
+        assert rungs >= 1
+
+
 def test_timeit_stats_and_row():
     import os
     import sys
