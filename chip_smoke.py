@@ -20,8 +20,10 @@ Phases, all pinned to the native Pallas kernels (``backend="pallas"``):
                replay and the f64 oracle may split near-ties.
   (b) stream - one lane of 200,000 requests (5.56M / 28, the published
                per-instance size) streamed through ``replay_stream`` with the
-               event-blocked kernel; usage, opened bins and every placement
-               must be bit-identical to the ``backend="jnp"`` stream.
+               event-blocked kernel, at ``BLOCK_EVENTS`` and with no
+               ``block_events`` (the size the code picks); usage, opened bins
+               and every placement must be bit-identical to the
+               ``backend="jnp"`` stream.
   (c) serve  - 20,000 Poisson requests through ``serve_traffic`` for a score
                and a category policy; every request answered, placements
                equal to the sequential host scheduler's.
@@ -190,9 +192,11 @@ def phase_shard(backend: str = "pallas", suite=SUITE, policies=POLICIES,
 
 def phase_stream(backend: str = "pallas", cfg=STREAM,
                  block_events: int = BLOCK_EVENTS):
-    """(b): the event-blocked streamed replay equals the jnp stream."""
+    """(b): the event-blocked streamed replay, at ``block_events`` and at
+    the block size the code picks, equals the jnp stream."""
     import numpy as np
 
+    from repro import obs
     from repro.stream import replay_stream, synthetic_source
 
     def once(be, T):
@@ -203,18 +207,24 @@ def phase_stream(backend: str = "pallas", cfg=STREAM,
                              max_bins=cfg["max_bins"], backend=be,
                              block_events=T, collect_placements=True)
 
-    got = once(backend, block_events)
     ref = once("jnp", 0)
-    _check(got.usage == ref.usage and got.opened == ref.opened,
-           f"stream usage/opened {got.usage}/{got.opened} != jnp "
-           f"{ref.usage}/{ref.opened}")
-    _check(np.array_equal(got.placements, ref.placements),
-           f"stream placements differ on "
-           f"{int((got.placements != ref.placements).sum())} items")
-    _check((got.placements >= 0).all(), "stream left items unplaced")
+    for label, T in ((f"T={block_events}", block_events), ("picked", None)):
+        b0 = obs.counter_get("stream.blocked_chunks")
+        got = once(backend, T)
+        _check(obs.counter_get("stream.blocked_chunks") - b0 ==
+               got.n_chunks, f"stream {label}: not every chunk blocked")
+        _check(got.usage == ref.usage and got.opened == ref.opened,
+               f"stream {label} usage/opened {got.usage}/{got.opened} != "
+               f"jnp {ref.usage}/{ref.opened}")
+        _check(np.array_equal(got.placements, ref.placements),
+               f"stream {label} placements differ on "
+               f"{int((got.placements != ref.placements).sum())} items")
+        _check((got.placements >= 0).all(),
+               f"stream {label} left items unplaced")
     return 2 * got.n_items, (f"{got.n_items} placements, usage "
                              f"{got.usage!r}, {got.opened} bins opened: "
-                             "bit-identical to jnp")
+                             f"T={block_events} and picked bit-identical "
+                             "to jnp")
 
 
 def _host_placements(reqs, policy, kwargs, caps, tps):

@@ -675,6 +675,28 @@ def replay_scan_steps(E: int, *, backend: str, block_events: int = 0,
     return E
 
 
+# Events per megakernel block of a chunked single-lane replay, chosen from
+# a sweep of block sizes on a TPU v5e (PERF.md, section 6).
+STREAM_BLOCK_EVENTS = 512
+
+
+def replay_block_events(policy: str, *, backend: str, L: int, max_bins: int,
+                        d: int, item_rows: int, chunk_events: int) -> int:
+    """``block_events`` for a chunked replay of this geometry: 0 (the
+    per-event scan) on jnp, the bit-exact reference, and whenever the
+    packed carry with its blocks passes ``VMEM_CAP_BYTES``; otherwise
+    ``STREAM_BLOCK_EVENTS``, or the whole chunk when it is shorter."""
+    T = min(STREAM_BLOCK_EVENTS, int(chunk_events))
+    if backend == "jnp" or T <= 1:
+        return 0
+    carry = jax.eval_shape(partial(
+        packed_init_carry, _KERNEL_FAMILY[policy_spec(policy).family], L,
+        item_rows, max_bins, d))
+    vmem = _fk.replay_block_vmem_bytes(
+        {nm: a.shape for nm, a in carry.items()}, T)
+    return T if vmem <= _fk.VMEM_CAP_BYTES else 0
+
+
 def replay_init_carry(policy: str, max_bins: int, d: int, item_rows: int,
                       *, L: int = 1, backend: str = "jnp",
                       block_events: int = 0):

@@ -9,7 +9,11 @@ device row pool, (2) replays the C events through
 (3) harvests the placements of rows the chunk freed, before they are
 recycled.  Usage / opened-bins / overflow accumulate inside the carry, so
 the last chunk's outputs are the full-run totals, bit-identical to the
-in-memory replay of the same event stream (tests/test_stream.py).
+in-memory replay of the same event stream (tests/test_stream.py).  On the
+kernel backends step (2) runs on the event-blocked megakernel whenever its
+packed carry fits VMEM (``jaxsim.replay_block_events``): one lane has no
+lanes to batch, and the per-event scan pays some fifty small device
+operations an event.
 
 Staging is double-buffered: with ``prefetch >= 1`` the host builds and
 ``device_put`` s up to that many chunks ahead while the device replays the
@@ -24,7 +28,9 @@ carry and pool of a pass; ``loads_bytes`` is the carried slot loads'
 size, which names the layout), ``stream.build`` (one chunk's heap merge and
 build), ``stream.put`` (its transfer), ``stream.step`` (its dispatch) and
 ``stream.fence`` (waiting on the device); ``stream.replay`` carries the
-events and chunks replayed.  One span per chunk, never one per event.
+events and chunks replayed and the ``block_events`` of the last pass (0:
+per-event).  One span per chunk, never one per event.  The counter
+``stream.blocked_chunks`` counts the chunks run on the megakernel.
 
 Memory is O(pool): the carry, the row pool and at most ``prefetch + 1``
 staged chunks - independent of trace length.  ``peak_device_bytes``
@@ -47,8 +53,8 @@ import numpy as np
 from .. import obs
 from ..core.jaxsim import (CapacityError, MAX_BINS_CAP, _replay_batch,
                            grow_live_items, grow_max_bins, policy_spec,
-                           replay_init_carry, replay_loads_shape,
-                           resolve_backend)
+                           replay_block_events, replay_init_carry,
+                           replay_loads_shape, resolve_backend)
 from ..kernels import fitscore as _fk
 from .events import ChunkedWorkload, InstanceSource, chunk_instance_events
 
@@ -159,15 +165,32 @@ def _nbytes(tree) -> int:
     return sum(x.nbytes for x in jax.tree.leaves(tree))
 
 
+class _OutgrewBlock(Exception):
+    """The row pool grew past what the megakernel's VMEM holds: the pass
+    restarts on the per-event path (events and chunks it replayed)."""
+
+    def __init__(self, events: int, chunks: int):
+        super().__init__(events, chunks)
+        self.events, self.chunks = events, chunks
+
+
 def _replay_once(source, policy, *, chunk_events, item_rows, max_bins,
                  backend, block_events, prefetch, grow_pool,
                  collect_placements, checkpointer):
+    """One pass over the source at ``max_bins`` slots.  ``block_events``
+    None lets ``replay_block_events`` pick the path from the pass's
+    geometry; returns (result, the ``block_events`` used)."""
     with obs.span("stream.init", max_bins=max_bins) as sp:
         wl = ChunkedWorkload(source, policy, chunk_events=chunk_events,
                              item_rows=item_rows, grow=grow_pool)
         d = wl.d
         rows = wl.item_rows
         sp.set(item_rows=rows)
+        pick = partial(replay_block_events, policy, backend=backend, L=1,
+                       max_bins=max_bins, d=d, chunk_events=chunk_events)
+        auto = block_events is None
+        if auto:
+            block_events = pick(item_rows=rows)
         carry = replay_init_carry(policy, max_bins, d, rows, L=1,
                                   backend=backend, block_events=block_events)
         sp.set(loads_bytes=4 * math.prod(replay_loads_shape(
@@ -203,6 +226,7 @@ def _replay_once(source, policy, *, chunk_events, item_rows, max_bins,
     harvest = []                # (freed_seqs, freed_place) per chunk
     last = None
     nchunks = resumed_chunks
+    nevents = 0
     peak = 0
     done = False
     while True:
@@ -225,16 +249,21 @@ def _replay_once(source, policy, *, chunk_events, item_rows, max_bins,
                 # the builder outgrew the pool: pad pool + carry (one retrace)
                 obs.counter_add("stream.pool_growths")
                 rows = ch.item_rows
+                if auto and block_events and not pick(item_rows=rows):
+                    raise _OutgrewBlock(nevents, nchunks - resumed_chunks)
                 pool = _grow_pool(pool, rows)
                 carry = _grow_carry(carry, rows)
             carry, pool, usage, opened, overflow, fp = _chunk_step(
                 carry, pool, *dev, policy=policy, max_bins=max_bins,
                 backend=backend, block_events=block_events, migrate=False,
                 harvest=collect_placements)
+        if block_events:
+            obs.counter_add("stream.blocked_chunks")
         if collect_placements:
             harvest.append((ch.freed_seqs, fp))
         last = (usage, opened, overflow)
         nchunks += 1
+        nevents += ch.n_events
         if depth == 0:
             with obs.span("stream.fence"):  # synchronous reference mode
                 jax.block_until_ready(carry)
@@ -258,39 +287,52 @@ def _replay_once(source, policy, *, chunk_events, item_rows, max_bins,
                 placements[seq] = final[row]
     return StreamResult(float(usage), int(opened), bool(overflow),
                         max_bins, wl.n_items, 2 * wl.n_items, nchunks,
-                        rows, int(peak), placements)
+                        rows, int(peak), placements), block_events
 
 
 def replay_stream(source, policy: str, *, chunk_events: int = 2048,
                   item_rows: int = 256, max_bins: int = 64,
                   max_bins_cap: int = MAX_BINS_CAP, auto_grow: bool = True,
-                  backend: Optional[str] = None, block_events: int = 0,
-                  prefetch: int = 1, grow_pool: bool = True,
-                  collect_placements: bool = False,
+                  backend: Optional[str] = None,
+                  block_events: Optional[int] = None, prefetch: int = 1,
+                  grow_pool: bool = True, collect_placements: bool = False,
                   checkpointer=None) -> StreamResult:
     """Replay one request stream under one policy in bounded memory.
 
     Bit-identical to ``jaxsim.simulate`` on the materialized instance
     (same events, same carry evolution, same escalation ladder); peak
     memory O(item-row pool + slot pool + staged chunks).  See the module
-    docstring for staging/prefetch semantics."""
+    docstring for staging/prefetch semantics.
+
+    ``block_events`` None (the default) picks the device path per pass
+    from the geometry (``jaxsim.replay_block_events``): the event-blocked
+    megakernel on kernel backends while the packed carry fits VMEM, else
+    the per-event scan; a pass whose pool outgrows VMEM restarts on the
+    per-event scan, which the rest of the call keeps.  An explicit value
+    (0: per-event) is used as given."""
     backend = resolve_backend(backend)
     policy_spec(policy)            # validate before any device work
     events = chunks = 0
     with obs.span("stream.replay", cat="stream", policy=policy,
                   backend=backend, chunk_events=int(chunk_events)) as sp:
         while True:
-            res = _replay_once(
-                source, policy, chunk_events=chunk_events,
-                item_rows=item_rows, max_bins=max_bins, backend=backend,
-                block_events=block_events, prefetch=prefetch,
-                grow_pool=grow_pool,
-                collect_placements=collect_placements,
-                checkpointer=checkpointer)
+            try:
+                res, used = _replay_once(
+                    source, policy, chunk_events=chunk_events,
+                    item_rows=item_rows, max_bins=max_bins,
+                    backend=backend, block_events=block_events,
+                    prefetch=prefetch, grow_pool=grow_pool,
+                    collect_placements=collect_placements,
+                    checkpointer=checkpointer)
+            except _OutgrewBlock as e:
+                events += e.events
+                chunks += e.chunks
+                block_events = 0
+                continue
             events += res.n_events
             chunks += res.n_chunks
             if not res.overflow or not auto_grow:
-                sp.set(events=events, chunks=chunks)
+                sp.set(events=events, chunks=chunks, block_events=used)
                 return res
             if max_bins >= max_bins_cap:
                 raise CapacityError(

@@ -147,3 +147,18 @@ def test_replay_block_rejects_geometry_past_vmem():
             c, a, b, s, m, family="hybrid", policy="first_fit", n=64, d=D),
             carry, ev_i, ev_f, jax.ShapeDtypeStruct((1, T, dpad), F32),
             jax.ShapeDtypeStruct((1, dpad), F32))
+
+
+def test_replay_block_compiles_at_picked_stream_geometry(one_chip):
+    """The block size ``replay_stream`` picks for the stream cell's
+    geometry (one lane, 512 slots, d = 5, 8,192 item rows, 2,048-event
+    chunks) compiles for a v5e."""
+    from repro.core.jaxsim import replay_block_events
+    T = replay_block_events("best_fit_linf", backend="pallas", L=1,
+                            max_bins=512, d=D, item_rows=8192,
+                            chunk_events=2048)
+    assert T > 1
+    carry = jax.eval_shape(lambda: packed_init_carry("score", 1, 8192, 512,
+                                                     D))
+    compiled = _compile_block(one_chip, carry, "score", 512, T, 2048)
+    assert "tpu_custom_call" in compiled.as_text()

@@ -280,6 +280,36 @@ def test_snapshot_in_old_select_layout_recomputed(small_batch, tmp_path,
     assert np.array_equal(res.n_bins_opened, base.n_bins_opened)
 
 
+def test_per_event_stream_snapshot_not_resumed_blocked(tmp_path):
+    """A stream snapshot written on the per-event path is keyed on its
+    ``block_events``: the blocked path the code picks does not resume it,
+    recomputes from the start and gives the per-event result bit for bit;
+    the per-event path still resumes it."""
+    from repro.resilience.checkpoint import StreamCheckpointer
+    from repro.stream import InstanceSource, replay_stream
+    src = InstanceSource(quantized_instance(5, n=60))
+    kw = dict(chunk_events=16, item_rows=32, max_bins=64,
+              backend="pallas_interpret")
+    per = replay_stream(src, "rcp", block_events=0, **kw,
+                        checkpointer=StreamCheckpointer(
+                            str(tmp_path), every_chunks=2, keep=True))
+    assert [f for f in os.listdir(tmp_path) if f.endswith(".npz")]
+    r0 = obs.counter_get("resilience.stream_ckpt_resume")
+    b0 = obs.counter_get("stream.blocked_chunks")
+    blocked = replay_stream(src, "rcp", **kw, checkpointer=StreamCheckpointer(
+        str(tmp_path), every_chunks=2, keep=True))
+    assert obs.counter_get("resilience.stream_ckpt_resume") == r0
+    assert obs.counter_get("stream.blocked_chunks") - b0 == blocked.n_chunks
+    assert (blocked.usage, blocked.opened, blocked.overflow,
+            blocked.max_bins) == (per.usage, per.opened, per.overflow,
+                                  per.max_bins)
+    again = replay_stream(src, "rcp", block_events=0, **kw,
+                          checkpointer=StreamCheckpointer(str(tmp_path),
+                                                          every_chunks=2))
+    assert obs.counter_get("resilience.stream_ckpt_resume") == r0 + 1
+    assert (again.usage, again.opened) == (per.usage, per.opened)
+
+
 def _migrate_stream(n=24, every=8):
     """A flattened single-lane event stream with MIGRATE events spliced
     across checkpoint-segment boundaries: each picks an item alive at its

@@ -12,6 +12,7 @@ import os
 import subprocess
 import sys
 
+import jax
 import numpy as np
 import pytest
 
@@ -20,6 +21,7 @@ from repro.api.workload import Setting, stream_source, synthetic
 from repro.core import jaxsim
 from repro.core.jaxsim import _replay_batch, simulate
 from repro.data.traces import _one_instance, load_azure_csv
+from repro.kernels import fitscore
 from repro.kernels.fitscore import (ARRIVAL_KIND, DEPARTURE_KIND,
                                     MIGRATE_KIND)
 from repro.resilience.checkpoint import StreamCheckpointer
@@ -116,6 +118,130 @@ def test_kernel_backend_chunked():
                             item_rows=48, max_bins=32,
                             backend="pallas_interpret", block_events=16)
         _assert_matches(res, ref, policy)
+
+
+# ------------------------------------------- the device path the code picks
+
+def _recorded_stream(inst, policy, **kw):
+    """``replay_stream`` under recording: (result, the ``stream.replay``
+    span's args, chunks counted by ``stream.blocked_chunks``, passes)."""
+    c0 = obs.counter_get("stream.blocked_chunks")
+    with obs.recording():
+        res = replay_stream(InstanceSource(inst), policy, **kw)
+        evs = obs.events()
+    rep, = [e for e in evs if e["name"] == "stream.replay"]
+    passes = sum(e["name"] == "stream.init" for e in evs)
+    return (res, rep["args"], obs.counter_get("stream.blocked_chunks") - c0,
+            passes)
+
+
+def _block_vmem(policy, item_rows, max_bins, d, T):
+    fam = jaxsim._KERNEL_FAMILY[jaxsim.policy_spec(policy).family]
+    carry = jax.eval_shape(lambda: jaxsim.packed_init_carry(
+        fam, 1, item_rows, max_bins, d))
+    return fitscore.replay_block_vmem_bytes(
+        {nm: a.shape for nm, a in carry.items()}, T)
+
+
+@pytest.mark.parametrize("policy,backend,max_bins,d,rows,chunk,want", [
+    ("best_fit_linf", "pallas", 512, 5, 8192, 2048,
+     jaxsim.STREAM_BLOCK_EVENTS),              # the stream cell
+    ("best_fit_linf", "jnp", 512, 5, 8192, 2048, 0),
+    ("hybrid", "pallas", 512, 5, 198_571, 2048, 0),   # identity table
+    ("rcp", "pallas_interpret", 64, 4, 24, 32, 32),   # chunk < block
+    ("first_fit", "pallas", 64, 4, 24, 1, 0),
+])
+def test_replay_block_events_rule(policy, backend, max_bins, d, rows, chunk,
+                                  want):
+    """The path is picked from the geometry alone: jnp and a one-event
+    chunk stay per-event, a packed carry past ``VMEM_CAP_BYTES`` (the
+    hybrid stream's whole 198,571-row item table) stays per-event, and
+    otherwise blocks of ``STREAM_BLOCK_EVENTS``, or of the whole chunk."""
+    assert jaxsim.replay_block_events(
+        policy, backend=backend, L=1, max_bins=max_bins, d=d,
+        item_rows=rows, chunk_events=chunk) == want
+    if policy == "hybrid":
+        assert _block_vmem(policy, rows, max_bins, d, 2048) > \
+            fitscore.VMEM_CAP_BYTES
+
+
+@pytest.mark.parametrize("policy", ("best_fit_l2", "cbd", "rcp"))
+def test_kernel_backend_picks_blocked_path(policy):
+    """With no ``block_events`` a kernel-backend stream runs every chunk on
+    the event-blocked megakernel and equals the jnp replay in usage, bins
+    opened and every placement."""
+    inst = _stream_instance(seed=6, n=60)
+    ref = simulate(inst, policy=policy, max_bins=64, backend="jnp")
+    res, args, blocked, _ = _recorded_stream(
+        inst, policy, chunk_events=32, item_rows=16, max_bins=64,
+        backend="pallas_interpret", collect_placements=True)
+    _assert_matches(res, ref, policy)
+    assert args["block_events"] == 32       # the whole (short) chunk
+    assert blocked == res.n_chunks > 1
+
+
+def test_hybrid_stream_past_vmem_stays_per_event(monkeypatch):
+    """A hybrid stream pins its whole item table; when that table's packed
+    carry passes the VMEM cap (as the stream cell's does) the stream runs
+    per-event, without error, and equals jnp."""
+    inst = _stream_instance(seed=6, n=60)
+    monkeypatch.setattr(fitscore, "VMEM_CAP_BYTES",
+                        _block_vmem("hybrid", inst.n_items, 64, 4, 32) - 1)
+    ref = simulate(inst, policy="hybrid", max_bins=64, backend="jnp")
+    res, args, blocked, _ = _recorded_stream(
+        inst, "hybrid", chunk_events=32, item_rows=16, max_bins=64,
+        backend="pallas_interpret", collect_placements=True)
+    _assert_matches(res, ref, "hybrid")
+    assert args["block_events"] == 0 and blocked == 0
+
+
+@pytest.mark.parametrize("when", ("start", "growth"))
+def test_pool_past_vmem_runs_per_event(monkeypatch, when):
+    """A packed carry past the VMEM cap keeps the stream per-event: from
+    the start, or, when the row pool grows past the cap mid-stream, by
+    restarting the pass per-event (the overflow ladder's restart); both
+    equal jnp."""
+    inst = _one_instance(11, 200, 4, 8, 30000.0, 1.6, "dense")
+    fits = _block_vmem("first_fit", 16, 64, 4, 16)
+    monkeypatch.setattr(fitscore, "VMEM_CAP_BYTES",
+                        fits - 1 if when == "start" else fits)
+    ref = simulate(inst, policy="first_fit", max_bins=64, backend="jnp")
+    g0 = obs.counter_get("stream.pool_growths")
+    res, args, blocked, passes = _recorded_stream(
+        inst, "first_fit", chunk_events=16, item_rows=16, max_bins=64,
+        backend="pallas_interpret", collect_placements=True)
+    _assert_matches(res, ref, when)
+    assert res.item_rows > 16 and obs.counter_get("stream.pool_growths") > g0
+    assert args["block_events"] == 0
+    if when == "start":
+        assert blocked == 0 and passes == 1
+    else:
+        assert blocked > 0 and passes == 2
+        assert args["chunks"] == res.n_chunks + blocked
+
+
+def test_jnp_never_picks_blocked_path():
+    """The jnp backend, the bit-exact reference, stays per-event."""
+    inst = _stream_instance(seed=6, n=60)
+    res, args, blocked, _ = _recorded_stream(
+        inst, "best_fit_l2", chunk_events=32, item_rows=16, max_bins=64,
+        backend="jnp")
+    assert args["block_events"] == 0 and blocked == 0
+    assert res.n_chunks > 1
+
+
+@pytest.mark.parametrize("block_events", (0, 16))
+def test_explicit_block_events_wins(block_events):
+    """An explicit ``block_events`` overrides the code's pick."""
+    inst = _stream_instance(seed=6, n=60)
+    ref = simulate(inst, policy="first_fit", max_bins=64, backend="jnp")
+    res, args, blocked, _ = _recorded_stream(
+        inst, "first_fit", chunk_events=32, item_rows=16, max_bins=64,
+        backend="pallas_interpret", block_events=block_events,
+        collect_placements=True)
+    _assert_matches(res, ref, block_events)
+    assert args["block_events"] == block_events
+    assert blocked == (res.n_chunks if block_events else 0)
 
 
 # ------------------------------------------------- boundary corner cases
