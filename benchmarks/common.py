@@ -4,16 +4,18 @@ Every paper figure gets one function returning rows
 (name, us_per_call, derived) where ``derived`` is the paper's metric - the
 mean performance ratio over the instance suite (usage time / Eq.(1) lower
 bound).  Scale knobs: BENCH_INSTANCES (default 12), BENCH_ITEMS (default
-2500), BENCH_REPEATS (default 1) - the paper uses 28 Azure instances; raise
-the knobs to reproduce at full scale.  If the real Azure trace is present
-under data/azure/, it is used instead of the synthetic family.
+2500), BENCH_REPEATS (default 1), read at each call (``scale``), so a run or
+a test sets them before its first figure - the paper uses 28 Azure
+instances; raise the knobs to reproduce at full scale.  If the real Azure
+trace is present under data/azure/, it is used instead of the synthetic
+family.
 
 Policies in ``jaxsim.SCAN_POLICIES`` - the score-based Any Fit family AND
 the category-structured families (hybrid, RCP/PPE, CBD/CBDT, lifetime
 alignment, adaptive) - are driven through the batched sweep runner
 (``repro.sweep``): the whole suite - and, for noise sweeps, all seeds -
-replays as one lane-batched scan per policy.  Set BENCH_SWEEP=0 to force
-everything through the host oracle engine instead.
+replays as one lane-batched scan per policy.  The rest (next_fit,
+rr_next_fit) run on the host oracle engine.
 """
 from __future__ import annotations
 
@@ -29,35 +31,37 @@ from repro.core import (BoxStats, get_algorithm, lognormal_predictions,
 from repro.data import load_azure_csv, make_azure_like_suite, \
     make_huawei_like_suite
 
-N_INSTANCES = int(os.environ.get("BENCH_INSTANCES", "12"))
-N_ITEMS = int(os.environ.get("BENCH_ITEMS", "2500"))
-REPEATS = int(os.environ.get("BENCH_REPEATS", "1"))
-USE_SWEEP = os.environ.get("BENCH_SWEEP", "1") != "0"
+def scale() -> Tuple[int, int, int]:
+    """(instances, items per instance, prediction seeds) of this run."""
+    return (int(os.environ.get("BENCH_INSTANCES", "12")),
+            int(os.environ.get("BENCH_ITEMS", "2500")),
+            int(os.environ.get("BENCH_REPEATS", "1")))
+
+
+def repeat_seeds() -> Tuple[int, ...]:
+    """The prediction seeds of the noise figures: BENCH_REPEATS of them."""
+    return tuple(range(scale()[2]))
 
 
 @functools.lru_cache()
-def azure_suite():
+def _suite_at(suite_name: str, n_instances: int, n_items: int):
+    if suite_name == "huawei":
+        return tuple(make_huawei_like_suite(n_instances=min(n_instances, 9),
+                                            n_items=max(n_items // 2, 500)))
     real = load_azure_csv()
     if real is not None:
         print("# using REAL Azure trace", flush=True)
         return tuple(real)
-    return tuple(make_azure_like_suite(n_instances=N_INSTANCES,
-                                       n_items=N_ITEMS))
-
-
-@functools.lru_cache()
-def huawei_suite():
-    return tuple(make_huawei_like_suite(n_instances=min(N_INSTANCES, 9),
-                                        n_items=max(N_ITEMS // 2, 500)))
+    return tuple(make_azure_like_suite(n_instances=n_instances,
+                                       n_items=n_items))
 
 
 def _suite(suite_name: str):
-    return azure_suite() if suite_name == "azure" else huawei_suite()
+    return _suite_at(suite_name, *scale()[:2])
 
 
-@functools.lru_cache()
-def _lb(suite_name: str, idx: int) -> float:
-    return lower_bound(_suite(suite_name)[idx])
+def azure_suite():
+    return _suite("azure")
 
 
 def _jaxsim_policy(name: str, kw: Dict) -> Optional[str]:
@@ -77,10 +81,9 @@ def alg(name: str, **kw):
     return f
 
 
-@functools.lru_cache()
 def _workload(suite_name: str):
-    """The bench suite wrapped as an api workload (registered once so the
-    facade reuses the packed batch across figure calls)."""
+    """The bench suite wrapped as an api workload (registered by content
+    digest, so the facade reuses the packed batch across figure calls)."""
     from repro.api import instances
     return instances(list(_suite(suite_name)), name=f"bench-{suite_name}")
 
@@ -102,8 +105,7 @@ def _evaluate_batched(policy: str, suite: str, sigma: Optional[float],
     exp = Experiment(wl, policies=(policy,), settings=(setting,),
                      seeds=tuple(seeds))
     from repro.sweep.grid import _built_suite
-    _built_suite(wl.suite())   # one-time suite prep outside the timing,
-    #                            mirroring the old lru-cached _packed/_lb
+    _built_suite(wl.suite())   # one-time suite prep outside the timing
     #                            (prediction sampling stays inside: it is
     #                            work the cell genuinely re-does per seed)
     t0 = time.time()
@@ -124,14 +126,14 @@ def evaluate(algorithm_factory, *, suite: str = "azure",
 
     Returns (per-instance mean ratios, wall seconds per run)."""
     policy = getattr(algorithm_factory, "jaxsim_policy", None)
-    if USE_SWEEP and policy is not None:
+    if policy is not None:
         return _evaluate_batched(policy, suite, sigma, eps, seeds)
     insts = _suite(suite)
     ratios = []
     t0 = time.time()
     n_runs = 0
-    for idx, inst in enumerate(insts):
-        lb = _lb(suite, idx)
+    for inst in insts:
+        lb = lower_bound(inst)
         per_seed = []
         for s in seeds:
             pdur = None

@@ -7,10 +7,9 @@ from __future__ import annotations
 
 from typing import List
 
-from .common import REPEATS, alg, box_row, evaluate
+from .common import alg, box_row, evaluate, repeat_seeds
 
 SIGMAS = [0.0, 0.5, 1.0, 2.0, 4.0]
-SEEDS = tuple(range(REPEATS))
 
 
 def fig2_bestfit_norms() -> List[str]:
@@ -83,7 +82,7 @@ def fig9_classify_error() -> List[str]:
     for sigma in SIGMAS:
         for name, f in [("cbdt_rho0.25d", alg("cbdt", rho=0.25 * 86400)),
                         ("cbd_beta2", alg("cbd", beta=2.0))]:
-            r, s = evaluate(f, sigma=sigma, seeds=SEEDS)
+            r, s = evaluate(f, sigma=sigma, seeds=repeat_seeds())
             out.append(box_row(f"fig9/{name}/sigma{sigma:g}", r, s))
     r, s = evaluate(alg("first_fit"))
     out.append(box_row("fig9/first_fit/flat", r, s))
@@ -94,7 +93,7 @@ def fig10_rcp_ppe() -> List[str]:
     out = []
     for sigma in SIGMAS:
         for name in ["rcp", "ppe", "rcp_modified", "ppe_modified"]:
-            r, s = evaluate(alg(name), sigma=sigma, seeds=SEEDS)
+            r, s = evaluate(alg(name), sigma=sigma, seeds=repeat_seeds())
             out.append(box_row(f"fig10/{name}/sigma{sigma:g}", r, s))
     return out
 
@@ -107,7 +106,7 @@ def fig11_lifetime_alignment() -> List[str]:
                  ("cbd_beta2", alg("cbd", beta=2.0)),
                  ("reduced_hybrid", alg("reduced_hybrid"))]
         for name, f in cases:
-            r, s = evaluate(f, sigma=sigma, seeds=SEEDS)
+            r, s = evaluate(f, sigma=sigma, seeds=repeat_seeds())
             out.append(box_row(f"fig11/{name}/sigma{sigma:g}", r, s))
     return out
 
@@ -120,7 +119,7 @@ def fig12_overall() -> List[str]:
                  ("ppe_modified", alg("ppe_modified")),
                  ("la_binary", alg("lifetime_alignment", mode="binary"))]
         for name, f in cases:
-            r, s = evaluate(f, sigma=sigma, seeds=SEEDS)
+            r, s = evaluate(f, sigma=sigma, seeds=repeat_seeds())
             out.append(box_row(f"fig12/{name}/sigma{sigma:g}", r, s))
     r, s = evaluate(alg("first_fit"))
     out.append(box_row("fig12/first_fit/flat", r, s))
@@ -149,7 +148,7 @@ def fig14_uniform_errors() -> List[str]:
                         ("ppe_modified", alg("ppe_modified")),
                         ("la_binary", alg("lifetime_alignment",
                                           mode="binary"))]:
-            r, s = evaluate(f, eps=eps, seeds=SEEDS)
+            r, s = evaluate(f, eps=eps, seeds=repeat_seeds())
             out.append(box_row(f"fig14/{name}/eps{eps:g}", r, s))
     return out
 
@@ -162,7 +161,7 @@ def fig15_adaptive() -> List[str]:
         for name, f in [("adaptive", alg("adaptive")),
                         ("nrt_prioritized", alg("nrt_prioritized")),
                         ("greedy", alg("greedy"))]:
-            r, s = evaluate(f, sigma=sigma, seeds=SEEDS)
+            r, s = evaluate(f, sigma=sigma, seeds=repeat_seeds())
             out.append(box_row(f"fig15/{name}/sigma{sigma:g}", r, s))
     r, s = evaluate(alg("first_fit"))
     out.append(box_row("fig15/first_fit/flat", r, s))

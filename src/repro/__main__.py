@@ -10,8 +10,9 @@ Subcommands:
     fleet baselines.  With ``--traffic {poisson,diurnal}`` it instead
     drives the live batched front end (admission queue -> double-buffered
     block dispatch) and reports throughput + placement latency.
-  * ``bench`` - the benchmark harness (``benchmarks.run``; requires the
-    repo root on sys.path, i.e. run from a checkout).
+  * ``figures`` - the paper's figures as CSV rows (``benchmarks.run``;
+    requires the repo root on sys.path, i.e. run from a checkout).  Speed
+    is measured by ``bench/run.py``, not here.
   * ``obs`` - summarize a JSONL observability run log (spans + counters),
     optionally converting it to Chrome/Perfetto trace_event JSON.
   * ``validate`` - input validation / quarantine dry run: build the suite
@@ -23,7 +24,7 @@ Subcommands:
     PYTHONPATH=src python -m repro serve --requests 2000 --sigma 0.5
     PYTHONPATH=src python -m repro serve --traffic poisson --rate 5e4 \
         --tps 1.2e5 --requests 2000
-    PYTHONPATH=src python -m repro bench --fast
+    PYTHONPATH=src python -m repro figures --fast
     PYTHONPATH=src python -m repro obs run.obs.jsonl --perfetto trace.json
     PYTHONPATH=src python -m repro validate --suites azure huawei
 """
@@ -126,14 +127,14 @@ def _serve(argv: Optional[List[str]]) -> None:
                   f"{b['replicas_opened']:>7d} {'-':>8}")
 
 
-def _bench(argv: Optional[List[str]]) -> None:
+def _figures(argv: Optional[List[str]]) -> None:
     try:
-        from benchmarks.run import main as bench_main
+        from benchmarks.run import main as figures_main
     except ImportError as e:
         raise SystemExit(
-            "python -m repro bench needs the repo checkout on sys.path "
+            "python -m repro figures needs the repo checkout on sys.path "
             f"(run from the repo root): {e}")
-    bench_main(argv)
+    figures_main(argv)
 
 
 def main(argv: Optional[List[str]] = None) -> None:
@@ -141,9 +142,9 @@ def main(argv: Optional[List[str]] = None) -> None:
     ap = argparse.ArgumentParser(
         prog="python -m repro",
         description=__doc__.splitlines()[0],
-        usage="python -m repro {sweep,serve,bench,obs,validate} ...")
+        usage="python -m repro {sweep,serve,figures,obs,validate} ...")
     ap.add_argument("command",
-                    choices=["sweep", "serve", "bench", "obs", "validate"])
+                    choices=["sweep", "serve", "figures", "obs", "validate"])
     args, rest = ap.parse_known_args(argv)
     from .compile_cache import enable
     enable()
@@ -159,7 +160,7 @@ def main(argv: Optional[List[str]] = None) -> None:
         from .resilience.validate import main as validate_main
         validate_main(rest)
     else:
-        _bench(rest)
+        _figures(rest)
 
 
 if __name__ == "__main__":

@@ -1,7 +1,7 @@
 """JAX's persistent compilation cache at a fixed place.
 
-Entry points (``python -m repro``, ``chip_smoke.py``, ``benchmarks/run.py``)
-call :func:`enable` before their first compile.  Where
+Entry points (``python -m repro``, its figure runner ``benchmarks/run.py``
+and ``chip_smoke.py``) call :func:`enable` before their first compile.  Where
 ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and this module
 sets no directory.  Otherwise the cache lives at ``<checkout>/.jax_cache``
 (listed in ``.gitignore``): a path with no temp name, pid or time in it,

@@ -24,8 +24,9 @@ recomputation and no per-item dict churn).
 All three sub-policies are *pool-stateless* (they read bin state from the
 shared BinPool and keep no private structures), so switching between them
 mid-stream is exactly an Any Fit algorithm and inherits Greedy/NRT's
-(mu+2)d+1 competitive bound in each regime.  Evaluated in
-benchmarks/figures.py (fig15_adaptive); validated in tests/test_adaptive.py.
+(mu+2)d+1 competitive bound in each regime.  Evaluated by the paper
+figure fig15_adaptive (``python -m repro figures --only fig15``); validated
+in tests/test_adaptive.py.
 """
 from __future__ import annotations
 

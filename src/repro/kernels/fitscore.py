@@ -2,15 +2,7 @@
 
 At cloud scale an arrival must be scored against thousands of bin slots
 x d resource dims: a bandwidth-bound stream over the loads matrix, ideal for
-VMEM tiling.  Three kernels live here:
-
-``fitscore`` (legacy scoring kernel)
-    Tiles of 256 bins x d(pad 128) are scored per grid step: feasibility
-    (all dims fit, ``EPS`` tolerance) + an l1/l2/linf fit score, and a
-    running argmin in SMEM scratch emits the chosen bin directly.  Ties are
-    broken by **opening order** (``open_seq``; defaults to slot index), the
-    same rule the oracle engine applies when it walks open bins in opening
-    order and takes the first minimum.
+VMEM tiling.  Two kernels live here:
 
 ``fitscore_select_batch`` (the sweep scan's placement step)
     The full fused placement decision for a *batch of lanes*, covering the
@@ -67,11 +59,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-EPS = 1e-9     # legacy fitscore tolerance (matches ref.fitscore_ref)
-BIG = 3.0e38   # python float: baked into the legacy kernel as an immediate
-
-NORMS = ("l1", "l2", "linf", "first_fit")
-
 # --- shared scoring semantics (core.jaxsim imports these; do not fork) ----
 SELECT_POLICIES = ("first_fit", "best_fit_l1", "best_fit_l2", "best_fit_linf",
                    "mru", "greedy", "nrt_standard", "nrt_prioritized")
@@ -108,103 +95,6 @@ LOC_G, LOC_B, LOC_C, LOC_L = 0, 1, 2, 3
 # Dense bound for RCP/PPE's carried per-category aggregates (geometric
 # prediction buckets X_i; bucket 63 would need a 2^62-second duration).
 KCAT = 64
-
-
-def _kernel(rem_ref, alive_ref, oseq_ref, item_ref, score_ref, best_ref,
-            sseq_ref, *, norm: str, bn: int, nb: int, n: int, d: int):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        best_ref[0] = jnp.float32(BIG)
-        best_ref[1] = jnp.float32(-1.0)
-        sseq_ref[0] = jnp.int32(IBIG)
-
-    rem = rem_ref[...].astype(jnp.float32)        # (bn, dpad)
-    item = item_ref[...].astype(jnp.float32)      # (1, dpad)
-    after = rem - item
-    dmask = jax.lax.broadcasted_iota(jnp.int32, after.shape, 1) < d
-    rows = i * bn + jax.lax.broadcasted_iota(jnp.int32, (bn, 1), 0)
-    oseq = oseq_ref[...]                          # (bn, 1) int32
-    alive = (alive_ref[...] > 0) & (rows < n)
-    feasible = jnp.all((after >= -EPS) | ~dmask, axis=1, keepdims=True) & alive
-
-    masked = jnp.where(dmask, after, 0.0)
-    if norm == "l1":
-        score = jnp.sum(masked, axis=1, keepdims=True)
-    elif norm == "l2":
-        score = jnp.sqrt(jnp.sum(masked * masked, axis=1, keepdims=True))
-    elif norm == "linf":
-        score = jnp.max(jnp.where(dmask, after, -BIG), axis=1, keepdims=True)
-    else:   # first_fit: prefer earliest-opened feasible bin
-        score = oseq.astype(jnp.float32)
-    score = jnp.where(feasible, score, BIG)
-    score_ref[...] = score
-
-    # (score, open_seq) lexicographic running argmin: the oracle walks open
-    # bins in opening order and keeps the first minimum, so score ties must
-    # fall to the earliest-opened bin - NOT the smallest slot index (a closed
-    # slot reused later has a small index but a late open_seq).
-    tile_best = jnp.min(score)
-    tied_seq = jnp.where((score == tile_best) & feasible, oseq, IBIG)
-    tile_seq = jnp.min(tied_seq)
-    tied_row = jnp.where(tied_seq == tile_seq, rows, IBIG)
-    tile_arg = jnp.min(tied_row)
-
-    better = (tile_best < best_ref[0]) | \
-        ((tile_best == best_ref[0]) & (tile_seq < sseq_ref[0]))
-
-    @pl.when(better)
-    def _upd():
-        best_ref[0] = tile_best
-        best_ref[1] = tile_arg.astype(jnp.float32)
-        sseq_ref[0] = tile_seq
-
-
-def fitscore(remaining, alive, item, open_seq=None, *, norm: str = "linf",
-             bn: int = 256, interpret: bool = False):
-    """remaining: (N,d); alive: (N,) bool/int; item: (d,); open_seq: (N,)
-    opening-order keys for tie-breaking (defaults to the slot index).
-    Returns (scores (N,), best_idx scalar int32, -1 if none feasible)."""
-    assert norm in NORMS
-    N, d = remaining.shape
-    dpad = max(128, -(-d // 128) * 128)
-    bn_ = min(bn, max(N, 8))
-    nb = -(-N // bn_)
-    rem_p = jnp.zeros((nb * bn_, dpad), remaining.dtype)
-    rem_p = rem_p.at[:N, :d].set(remaining)
-    alive_p = jnp.zeros((nb * bn_, 1), jnp.int32).at[:N, 0].set(
-        alive.astype(jnp.int32))
-    if open_seq is None:
-        open_seq = jnp.arange(N, dtype=jnp.int32)
-    oseq_p = jnp.full((nb * bn_, 1), IBIG, jnp.int32).at[:N, 0].set(
-        open_seq.astype(jnp.int32))
-    item_p = jnp.zeros((1, dpad), remaining.dtype).at[0, :d].set(item)
-
-    kernel = functools.partial(_kernel, norm=norm, bn=bn_, nb=nb, n=N, d=d)
-    scores, best = pl.pallas_call(
-        kernel,
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((bn_, dpad), lambda i: (i, 0)),
-            pl.BlockSpec((bn_, 1), lambda i: (i, 0)),
-            pl.BlockSpec((bn_, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, dpad), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bn_, 1), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nb * bn_, 1), jnp.float32),
-            jax.ShapeDtypeStruct((2,), jnp.float32),
-        ],
-        scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
-        interpret=interpret,
-    )(rem_p, alive_p, oseq_p, item_p)
-    scores = jnp.where(scores[:N, 0] >= BIG, jnp.inf, scores[:N, 0])
-    best_idx = jnp.where(best[0] >= BIG, -1, best[1]).astype(jnp.int32)
-    return scores, best_idx
 
 
 # ======================================================================

@@ -2,7 +2,7 @@
 
 On TPU backends the Pallas kernels run natively; elsewhere the pure-jnp
 references (ref.py) run so the whole framework works identically on CPU
-(dry-run, tests).  ``impl="pallas_interpret"`` forces the kernel body in
+(tests).  ``impl="pallas_interpret"`` forces the kernel body in
 interpret mode (the correctness harness used by tests/test_kernels.py).
 """
 from __future__ import annotations
@@ -14,8 +14,6 @@ import jax.numpy as jnp
 
 from . import ref
 from .decode_attention import decode_attention as _decode_pallas
-from .fitscore import IBIG
-from .fitscore import fitscore as _fitscore_pallas
 from .flash_attention import flash_attention as _flash_pallas
 from .rwkv6_scan import rwkv6_chunked as _rwkv6_pallas
 from ..resilience import faults
@@ -89,33 +87,6 @@ def rwkv6(r, k, v, logw, u, *, chunk=16, impl="auto"):
     if impl == "pallas_interpret":
         return _rwkv6_pallas(r, k, v, logw, u, chunk=chunk, interpret=True)
     return ref.rwkv6_ref(r, k, v, jnp.clip(logw, -4.0, 0.0), u)
-
-
-@partial(jax.jit, static_argnames=("norm", "impl"))
-def fitscore(remaining, alive, item, open_seq=None, *, norm="linf",
-             impl="auto"):
-    """Scores + chosen bin.  Ties break by ``open_seq`` (opening order, the
-    oracle's rule); ``open_seq=None`` means slot index == opening order."""
-    if _use_pallas(impl):
-        return _fitscore_pallas(remaining, alive, item, open_seq, norm=norm)
-    if impl == "pallas_interpret":
-        return _fitscore_pallas(remaining, alive, item, open_seq, norm=norm,
-                                interpret=True)
-    n = remaining.shape[0]
-    if open_seq is None:
-        open_seq = jnp.arange(n, dtype=jnp.int32)
-    if norm == "first_fit":
-        feasible = jnp.all(remaining - item[None, :] >= -1e-9, axis=1) & \
-            (alive > 0)
-        scores = jnp.where(feasible, open_seq.astype(jnp.float32), jnp.inf)
-    else:
-        scores, feasible = ref.fitscore_ref(remaining, alive > 0, item,
-                                            norm=norm)
-    tie = scores <= jnp.min(scores)
-    best = jnp.argmin(jnp.where(tie, open_seq.astype(jnp.int32),
-                                jnp.int32(IBIG)))
-    best = jnp.where(jnp.isinf(scores).all(), -1, best)
-    return scores, best.astype(jnp.int32)
 
 
 def fitscore_select(loads, counts, alive, open_seq, access_seq, closes,

@@ -4,10 +4,8 @@ Deliberately the simplest correct implementations: full score-matrix softmax
 for attention, a sequential lax.scan over time for RWKV6 - no chunking, no
 blocking, no numerical tricks beyond fp32 softmax.
 
-``fitscore_ref`` scores only; the (score, opening-order) tie-break and
-free-slot selection that complete the placement decision live in
-``kernels.ops.fitscore`` / ``core.jaxsim._select_slot`` (and fused in the
-``kernels.fitscore`` Pallas kernels).
+The placement kernels in ``kernels.fitscore`` have their jnp twin in
+``core.jaxsim._select_slot`` instead.
 """
 from __future__ import annotations
 
@@ -76,20 +74,3 @@ def rwkv6_ref(r, k, v, logw, u, *, initial_state=None):
     xs = tuple(jnp.moveaxis(a, 1, 0) for a in (r, k, v, logw))
     state, ys = jax.lax.scan(step, state, xs)
     return jnp.moveaxis(ys, 0, 1), state
-
-
-def fitscore_ref(remaining, alive, item, *, norm="linf", eps=1e-9):
-    """DVBP placement scoring (the paper's inner loop, vectorized).
-
-    remaining: (N,d) available capacity per bin; alive: (N,) bool;
-    item: (d,).  Returns (scores (N,) with +inf where infeasible, feasible
-    mask (N,)).  Score = l_p norm of capacity left after placement."""
-    rem_after = remaining - item[None, :]
-    feasible = jnp.all(rem_after >= -eps, axis=1) & alive
-    if norm == "l1":
-        score = rem_after.sum(axis=1)
-    elif norm == "l2":
-        score = jnp.sqrt(jnp.sum(rem_after * rem_after, axis=1))
-    else:
-        score = rem_after.max(axis=1)
-    return jnp.where(feasible, score, jnp.inf), feasible

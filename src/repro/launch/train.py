@@ -3,9 +3,9 @@
     PYTHONPATH=src python -m repro.launch.train --arch qwen2.5-14b --reduced \
         --steps 100 --batch 8 --seq 128
 
-Full-scale configs target the production mesh (see dryrun.py for the
-compile-only path); --reduced runs the same code end-to-end on host devices
-with checkpointing, deterministic data, and metrics.
+Full-scale configs target the production mesh; --reduced runs the same
+code end-to-end on host devices with checkpointing, deterministic data, and
+metrics.
 """
 from __future__ import annotations
 

@@ -1,9 +1,8 @@
 """Parameter templates: one source of truth for shapes, logical axes, init.
 
 ``template(cfg)`` returns a pytree of ``ParamMeta`` describing every weight.
-``init_params`` materializes it; ``abstract_params`` gives ShapeDtypeStructs
-(for the dry-run); ``sharding.tree_pspecs`` maps the logical axes to mesh
-PartitionSpecs.  Layer blocks are stacked along a leading "layers" axis so the
+``init_params`` materializes it; ``abstract_params`` gives ShapeDtypeStructs;
+``sharding.tree_pspecs`` maps the logical axes to mesh PartitionSpecs.  Layer blocks are stacked along a leading "layers" axis so the
 stacks are consumed by ``lax.scan`` (constant compile time in depth).
 """
 from __future__ import annotations

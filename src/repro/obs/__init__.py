@@ -18,20 +18,19 @@ must stay outside jitted computations (a traced body runs once, at trace
 time).  Per-event device data never goes through the collector - it rides
 out of the scan as stacked outputs (``ReplayTrace``).
 """
-from .collector import (HIST_BOUNDS, Span, TimingStats, annotate,
-                        counter_add, counter_deltas, counter_get,
-                        counter_hist, counter_ops, counters, disable, enable,
-                        enabled, events, instant, recording, reset, span,
-                        timeit, traced)
+from .collector import (HIST_BOUNDS, Span, annotate, counter_add,
+                        counter_deltas, counter_get, counter_hist,
+                        counter_ops, counters, disable, enable, enabled,
+                        events, instant, recording, reset, span, traced)
 from .export import (chrome_trace_events, export_jsonl, export_perfetto,
                      read_jsonl, summarize)
 from .trace import (ReplayTrace, TraceDivergence, diff_traces, from_scan)
 
 __all__ = [
-    "HIST_BOUNDS", "Span", "TimingStats", "annotate", "counter_add",
-    "counter_deltas", "counter_get", "counter_hist", "counter_ops",
-    "counters", "disable", "enable", "enabled", "events", "instant",
-    "recording", "reset", "span", "timeit", "traced",
+    "HIST_BOUNDS", "Span", "annotate", "counter_add", "counter_deltas",
+    "counter_get", "counter_hist", "counter_ops", "counters", "disable",
+    "enable", "enabled", "events", "instant", "recording", "reset", "span",
+    "traced",
     "chrome_trace_events", "export_jsonl", "export_perfetto",
     "read_jsonl", "summarize",
     "ReplayTrace", "TraceDivergence", "diff_traces", "from_scan",

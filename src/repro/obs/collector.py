@@ -11,8 +11,7 @@ Two tiers, tuned so instrumentation can stay in every hot path:
     (``obs.enable()`` / ``obs.recording()`` / env ``REPRO_OBS=1``).  When
     disabled, ``span()`` returns a shared no-op object: the cost of an
     instrumented-but-disabled call site is one flag check and one function
-    call, and ``jax`` is never touched (the <2% overhead budget is asserted
-    by ``benchmarks/perf.py::obs_overhead``).  A recorded span also opens a
+    call, and ``jax`` is never touched.  A recorded span also opens a
     ``jax.profiler.TraceAnnotation`` of its name, so every span lands in
     the host plane of any ``jax.profiler`` trace running around it, on the
     same clock as the device operations.  That costs a few microseconds a
@@ -35,10 +34,8 @@ counter registry are process-global behind a lock.
 """
 from __future__ import annotations
 
-import dataclasses
 import functools
 import os
-import statistics
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
@@ -273,59 +270,3 @@ def recording(clear: bool = True) -> _Recording:
     """``with obs.recording(): ...`` - enable spans for the block (and by
     default start from an empty event buffer)."""
     return _Recording(clear)
-
-
-# ------------------------------------------------------------------ timeit
-
-@dataclasses.dataclass(frozen=True)
-class TimingStats:
-    """Per-rep wall-clock stats from ``obs.timeit`` (seconds)."""
-    reps: tuple
-
-    @property
-    def n(self) -> int:
-        return len(self.reps)
-
-    @property
-    def best(self) -> float:
-        return min(self.reps)
-
-    @property
-    def median(self) -> float:
-        return statistics.median(self.reps)
-
-    @property
-    def mean(self) -> float:
-        return statistics.fmean(self.reps)
-
-    @property
-    def stdev(self) -> float:
-        return statistics.stdev(self.reps) if len(self.reps) > 1 else 0.0
-
-    def row(self, name: str, derived, scale: float = 1.0) -> str:
-        """A ``benchmarks`` CSV row carrying the spread as a structured
-        comment (parsed into the bench JSON by ``benchmarks/run.py``).
-        ``scale`` converts per-call times to the row's unit (e.g. 1/E for
-        a per-event row)."""
-        s = scale * 1e6
-        return (f"{name},{self.best * s:.1f},{derived}"
-                f"  # med={self.median * s:.1f}us"
-                f" sd={self.stdev * s:.1f}us n={self.n}")
-
-
-def timeit(fn: Callable, *args, n: int = 5, warmup: int = 1,
-           **kw) -> TimingStats:
-    """Time ``fn(*args, **kw)`` with ``perf_counter``, blocking on device
-    results (``jax.block_until_ready`` over whatever it returns) so the
-    measurement covers execution, not dispatch.  ``warmup`` reps first
-    (compile + cache warm), then ``n`` measured reps; returns min / median
-    / stdev instead of a single best-of-N wall-clock sample."""
-    import jax
-    for _ in range(max(0, warmup)):
-        jax.block_until_ready(fn(*args, **kw))
-    reps = []
-    for _ in range(max(1, n)):
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn(*args, **kw))
-        reps.append(time.perf_counter() - t0)
-    return TimingStats(tuple(reps))

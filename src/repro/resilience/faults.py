@@ -36,8 +36,7 @@ Activation: ``install(plan)`` / ``clear()`` in-process, the ``injected``
 context manager for tests, or env ``REPRO_FAULTS`` for subprocesses -
 a comma list of ``site:kind[:at[:count[:delay]]]``, e.g.
 ``REPRO_FAULTS="sweep.group:kill:3"``.  With no plan installed ``fire``
-is two global reads - cheap enough to sit on every hot path
-(benchmarks/perf.py::resilience_overhead asserts the budget).
+is two global reads - cheap enough to sit on every hot path.
 """
 from __future__ import annotations
 

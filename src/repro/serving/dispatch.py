@@ -32,7 +32,8 @@ demanded.
 small fixed set of block sizes (default 1/8/32/256), so the jit trace
 count stays bounded; ``serving.jit_trace`` / ``serving.jit_cache_hit``
 counters (off ``kernels.ops.dispatch_trace_count``) are the monitored
-invariant, gated in CI like ``perf/sweep_retrace_6x2v12x1``.
+invariant: a warm pass over known geometries traces nothing
+(``tests/test_dispatch.py::test_retrace_bounded_by_geometries``).
 
 **Degradation ladder.**  Every dispatch crosses the ``serving.select``
 fault seam per rung: the configured block engine, then the same engine

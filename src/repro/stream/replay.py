@@ -20,8 +20,8 @@ Staging is double-buffered: with ``prefetch >= 1`` the host builds and
 current one, and nothing fences until the final resolve - jax's async
 dispatch overlaps host merge/CSV work with device compute exactly as the
 serving front end's block placement does.  ``prefetch=0`` is the
-synchronous reference (fence after every chunk), kept for the
-``perf/stream_prefetch`` comparison.
+synchronous mode (fence after every chunk), which the tests replay
+against the prefetching one.
 
 Recorded spans mark the staging boundaries: ``stream.init`` (builder,
 carry and pool of a pass; ``loads_bytes`` is the carried slot loads'

@@ -169,6 +169,18 @@ def test_run_batch_wires_the_driver(pair):
     assert (res.usage_time < base.usage_time).any()
 
 
+@pytest.mark.parametrize("policy", ("first_fit", "greedy"))
+def test_consolidation_never_raises_usage(policy, pair):
+    """For these score policies a whole-bin drain saves usage time on
+    every lane and never costs any.  It is not an invariant of every
+    family: on this fixture cbd's usage rises under the same drain."""
+    _, batch = pair
+    base = run_batch(batch, policy, max_bins=32)
+    res = run_batch(batch, policy, max_bins=32, consolidate=SPEC)
+    assert (res.usage_time <= base.usage_time).all()
+    assert (res.usage_time < base.usage_time).any()
+
+
 # --------------------------------------------------------- budget + counters
 
 def test_budget_bounds_churn(pair):

@@ -86,50 +86,3 @@ def test_rwkv6_chunked_matches_sequential_model_path():
                                atol=1e-4, rtol=1e-4)
     np.testing.assert_allclose(np.asarray(s_model), np.asarray(s_ref),
                                atol=1e-4, rtol=1e-4)
-
-
-@pytest.mark.parametrize("N,d,norm", [
-    (100, 4, "linf"), (1000, 5, "l1"), (37, 2, "l2"), (300, 4, "first_fit"),
-    (8, 4, "linf"), (256, 1, "linf"),
-])
-def test_fitscore(N, d, norm):
-    rng = np.random.default_rng(0)
-    rem = jnp.array(rng.random((N, d)))
-    alive = jnp.array(rng.random(N) > 0.3)
-    item = jnp.array(rng.random(d) * 0.5)
-    s1, b1 = ops.fitscore(rem, alive, item, norm=norm,
-                          impl="pallas_interpret")
-    s2, b2 = ops.fitscore(rem, alive, item, norm=norm, impl="ref")
-    np.testing.assert_allclose(np.nan_to_num(np.asarray(s1), posinf=1e9),
-                               np.nan_to_num(np.asarray(s2), posinf=1e9),
-                               atol=1e-5, rtol=1e-5)
-    assert int(b1) == int(b2) or float(s2[b1]) == pytest.approx(
-        float(s2[b2]))
-
-
-def test_fitscore_no_feasible():
-    rem = jnp.zeros((10, 3))
-    alive = jnp.ones(10, bool)
-    item = jnp.ones(3) * 0.5
-    _, b = ops.fitscore(rem, alive, item, impl="pallas_interpret")
-    assert int(b) == -1
-
-
-@pytest.mark.parametrize("impl", ["ref", "pallas_interpret"])
-def test_fitscore_ties_break_by_open_seq(impl):
-    """Score ties fall to the earliest-*opened* bin (the oracle's rule), not
-    the smallest slot index: slots 0/2 tie but slot 2 opened first."""
-    rem = jnp.array([[0.5, 0.5], [0.125, 0.75], [0.5, 0.5]])
-    alive = jnp.ones(3, bool)
-    item = jnp.array([0.25, 0.25])
-    open_seq = jnp.array([7, 3, 1], jnp.int32)
-    for norm in ("l1", "l2", "linf"):
-        _, b = ops.fitscore(rem, alive, item, open_seq, norm=norm, impl=impl)
-        assert int(b) == 2, (impl, norm)
-    # without open_seq the slot index is the opening order: slot 0 wins
-    _, b = ops.fitscore(rem, alive, item, norm="linf", impl=impl)
-    assert int(b) == 0, impl
-    # first_fit scores ARE the opening order
-    _, b = ops.fitscore(rem, alive, item, open_seq, norm="first_fit",
-                        impl=impl)
-    assert int(b) == 2, impl

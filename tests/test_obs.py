@@ -212,30 +212,6 @@ def test_run_batch_span_counts_category_state(policy, backend, block_events,
         assert rungs >= 1
 
 
-def test_timeit_stats_and_row():
-    import os
-    import sys
-    st = obs.timeit(lambda: sum(range(100)), n=4, warmup=1)
-    assert st.n == 4 and st.best <= st.median <= max(st.reps)
-    assert st.stdev >= 0 and st.mean > 0
-    row = st.row("perf/x", "1.23", scale=0.5)
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-    from benchmarks.run import _parse_row
-    parsed = _parse_row(row)
-    assert parsed["name"] == "perf/x" and parsed["derived"] == 1.23
-    assert parsed["reps"] == 4
-    assert parsed["us_per_call"] == pytest.approx(st.best * 0.5e6, abs=0.1)
-    assert parsed["median_us"] == pytest.approx(st.median * 0.5e6, abs=0.1)
-    # one-shot rows (no spread comment) normalize to the same schema:
-    # median_us == us_per_call, stdev 0, one rep
-    plain = _parse_row("perf/y,12,0.5")
-    assert plain["median_us"] == 12.0
-    assert plain["stdev_us"] == 0.0 and plain["reps"] == 1
-    # CPU-interpret Pallas rows carry the mode tag off the comment token
-    assert _parse_row("perf/z,3,1  # mode=interpret")["mode"] == "interpret"
-    assert "mode" not in plain
-
-
 def test_kind_constants_match_kernel():
     from repro.kernels import fitscore
     assert ARRIVAL_KIND == fitscore.ARRIVAL_KIND

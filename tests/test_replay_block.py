@@ -165,8 +165,8 @@ def test_one_trace_across_grid():
     not the padded geometry (L, n_max, d, max_bins, T) - compile exactly
     once per policy: the jitted replay is keyed on the flattened lane
     layout, not the (B, S) split (regression: 6x2 and 12x1 grids used to
-    retrace).  Retraces are read off the ``sweep.jit_trace`` obs counter -
-    the same signal ``benchmarks/perf.py::sweep_retrace`` gates in CI."""
+    retrace).  Retraces are read off the ``sweep.jit_trace`` obs counter:
+    zero after the first cell is the invariant."""
     from repro import obs
     i6 = [quantized_instance(40 + k, 30, 3) for k in range(6)]
     i12 = [quantized_instance(60 + k, 30, 3) for k in range(12)]

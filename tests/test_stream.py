@@ -165,12 +165,16 @@ def test_replay_block_events_rule(policy, backend, max_bins, d, rows, chunk,
             fitscore.VMEM_CAP_BYTES
 
 
-@pytest.mark.parametrize("policy", ("best_fit_l2", "cbd", "rcp"))
+@pytest.mark.parametrize("policy", jaxsim.SCAN_POLICIES)
 def test_kernel_backend_picks_blocked_path(policy):
     """With no ``block_events`` a kernel-backend stream runs every chunk on
     the event-blocked megakernel and equals the jnp replay in usage, bins
-    opened and every placement."""
+    opened and every placement, in every carry family.  Every family's
+    packed carry fits VMEM here, even at the whole instance's rows (the
+    row pool's bound); the two tests below run carries past the cap."""
     inst = _stream_instance(seed=6, n=60)
+    assert _block_vmem(policy, inst.n_items, 64, inst.d, 32) <= \
+        fitscore.VMEM_CAP_BYTES
     ref = simulate(inst, policy=policy, max_bins=64, backend="jnp")
     res, args, blocked, _ = _recorded_stream(
         inst, policy, chunk_events=32, item_rows=16, max_bins=64,
@@ -535,12 +539,11 @@ def test_lane_padding_when_devices_dwarf_lanes():
     assert "PAD-OK" in proc.stdout
 
 
-# ------------------------------------------------------------ bench gate
+# ------------------------------------------------------- bounded-memory smoke
 
 def test_stream_smoke_matches_simulate():
-    """The CI smoke lane's gate: a 3k-item (6k-event) stream replays
-    bit-identically with a bounded pool (the perf/stream_replay_6k row
-    asserts exactly this before timing)."""
+    """A 3k-item (6k-event) stream replays bit-identically with a row pool
+    smaller than the instance (memory O(max alive), not O(trace))."""
     src = synthetic_source(3000, seed=17)
     inst = src.inst
     ref = simulate(inst, policy="first_fit", max_bins=128)
