@@ -10,6 +10,12 @@ allocatable again from the *next* chunk on (never inside the chunk that
 freed it, so the chunk's pool scatter happens once, up front).  Peak pool
 size is therefore O(max concurrently alive VMs), not O(trace length).
 
+The builder works a block of records at a time, in NumPy: one conversion
+per column, one ``np.lexsort`` merging the block's arrivals with the due
+departures (pending departures are sorted parallel arrays, not a heap),
+and whole-slice writes into each chunk.  Its memory is O(alive VMs + one
+block).
+
 Event ordering is bit-compatible with ``core.jaxsim.event_sequence``:
 events sort by time (compared in float64, exactly as the in-memory
 ``np.lexsort`` does before the device cast to float32), departures before
@@ -36,8 +42,8 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
-import heapq
-from typing import Iterable, Iterator, Optional, Tuple
+import itertools
+from typing import Iterator, Optional, Tuple
 
 import numpy as np
 
@@ -49,6 +55,11 @@ from ..kernels.fitscore import ARRIVAL_KIND, DEPARTURE_KIND, KCAT, PAD_KIND
 # Scatter index for padding rows of the per-chunk pool update: far out of
 # range, dropped by the device scatter's mode="drop".
 POOL_SENTINEL = np.int32(2 ** 30)
+
+# Records the chunk builder takes from its source at a time: each block is
+# converted once per column and merged by one sort, so the builder's memory
+# is O(alive VMs + one block).
+RECORD_BLOCK = 4096
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,9 +96,11 @@ class InstanceSource:
 
     def records(self) -> Iterator[Tuple[np.ndarray, float, float, float]]:
         inst = self.inst
-        for i in range(inst.n_items):
-            yield (inst.sizes[i], float(inst.arrivals[i]),
-                   float(inst.departures[i]), float(self.pdeps[i]))
+        for s in range(0, inst.n_items, RECORD_BLOCK):
+            e = s + RECORD_BLOCK
+            yield from zip(inst.sizes[s:e], inst.arrivals[s:e].tolist(),
+                           inst.departures[s:e].tolist(),
+                           self.pdeps[s:e].tolist())
 
     def full_arrays(self):
         """(sizes, arrivals, rdeps, pdeps) float64 - identity (hybrid)
@@ -148,17 +161,67 @@ class EventChunk:
     final: bool
 
 
+class _OpenChunk:
+    """The chunk being filled: its (C,) event and pool-update columns, PAD
+    and ``POOL_SENTINEL`` padded, and the rows its departures freed."""
+
+    def __init__(self, C: int, d: int, x0: int):
+        self.times = np.zeros(C, np.float32)
+        self.kinds = np.full(C, PAD_KIND, np.int32)
+        self.items = np.zeros(C, np.int32)
+        self.extra = np.full(C, x0, np.int32)   # PADs carry the start value
+        self.upd_idx = np.full(C, POOL_SENTINEL, np.int32)
+        self.upd_size = np.zeros((C, d), np.float32)
+        self.upd_arrival = np.zeros(C, np.float32)
+        self.upd_rdep = np.zeros(C, np.float32)
+        self.upd_pdep = np.zeros(C, np.float32)
+        self.freed: list = []
+        self.freed_seqs: list = []
+        self.fill = 0               # events written
+        self.nupd = 0               # pool updates written
+
+
+def _columns(recs, d: int):
+    """(sizes f32 (n, d), arrivals, rdeps, pdeps f64) of a block of
+    records: one conversion per column."""
+    size, arr, rdep, pdep = zip(*recs)
+    return (np.array(size, np.float32)[:, :d], np.array(arr, np.float64),
+            np.array(rdep, np.float64), np.array(pdep, np.float64))
+
+
+def _check_block(arr, rdep, last_arr: float) -> None:
+    """The per-record checks, in record order: arrival-sorted (across
+    blocks too), then departure after arrival."""
+    prev = np.concatenate(([last_arr], arr[:-1]))
+    unsorted = arr < prev
+    bad = unsorted | ~(rdep > arr)
+    if bad.any():
+        i = int(np.argmax(bad))
+        if unsorted[i]:
+            raise ValueError(
+                f"stream not arrival-sorted: {arr[i]} after {prev[i]}")
+        raise ValueError(f"departure {rdep[i]} <= arrival {arr[i]}")
+
+
 class ChunkedWorkload:
     """Merge a request stream into arrival/departure events and cut them
     into :class:`EventChunk` s over a recycled row pool.
 
-    The pending-departure heap is keyed ``(departure_time, item_seq)`` -
-    together with "drain every departure whose time <= the next arrival's
-    time first", this reproduces the in-memory event order exactly (time,
-    then departures-before-arrivals, then source position).  ``grow``
-    doubles the pool when the alive population outruns it (the driver
-    re-traces once per growth); identity mode disables recycling and pins
-    ``item_rows`` to the full item count."""
+    Records are pulled ``record_block`` at a time.  Pending departures are
+    parallel arrays sorted by ``(departure_time, item_seq)``.  A block's
+    arrivals are merged with every pending or in-block departure whose
+    time is <= the block's last arrival by one ``np.lexsort`` on (time,
+    departures before arrivals, item seq); later departures stay pending.
+    This reproduces the in-memory event order exactly (time, then
+    departures-before-arrivals, then source position).  The merged events
+    fill chunks in slices: a chunk's arrivals take, in arrival order, the
+    smallest rows freed by earlier chunks, then fresh rows.  Memory is
+    O(alive VMs + one block), never O(trace length).  ``grow`` doubles the
+    pool when the alive population outruns it (the driver re-traces once
+    per growth); identity mode disables recycling and pins ``item_rows``
+    to the full item count."""
+
+    record_block = RECORD_BLOCK
 
     def __init__(self, source, policy: str, *, chunk_events: int = 2048,
                  item_rows: int = 256, grow: bool = True,
@@ -179,137 +242,176 @@ class ChunkedWorkload:
         self.grow = bool(grow)
         self.d = source.meta().d
         # live pool state (populated while chunks() runs)
-        self._row_seq = {}          # pool row -> global item seq, alive only
-        self._seq_count = 0
-        self._done = False
+        self._row_seq = np.full(self.item_rows, -1, np.int64)  # alive seq
+        self._seq_count = 0         # items placed into chunks
+        self.records_pulled = 0     # records taken from the source
 
     # ------------------------------------------------------------ builder
     def chunks(self) -> Iterator[EventChunk]:
         C, d = self.chunk_events, self.d
+        B = max(int(self.record_block), 1)
         rcp = self.spec.family == "rcp"
-        free: list = []             # allocatable rows (min-heap)
+        records = iter(self.source.records())
+        # pending departures, sorted by (time, seq), rows assigned
+        pend_t = np.empty(0, np.float64)
+        pend_seq = np.empty(0, np.int64)
+        pend_row = np.empty(0, np.int64)
+        free = np.empty(0, np.int64)    # allocatable at the chunk's start
+        used = 0                        # ... of which its arrivals took
         next_fresh = 0
-        heap: list = []             # (dep_time f64, seq, row) pending deps
-        seen_cats = [False] * KCAT
-        xcount = 0
+        seen_cats = np.zeros(KCAT, bool)
+        xcount = 0                      # RCP count after the merged events
         last_arr = -np.inf
-
-        ev_t = np.zeros(C, np.float32)
-        ev_k = np.full(C, PAD_KIND, np.int32)
-        ev_i = np.zeros(C, np.int32)
-        ev_x = np.zeros(C, np.int32)
-        upd_idx = np.full(C, POOL_SENTINEL, np.int32)
-        upd_size = np.zeros((C, d), np.float32)
-        upd_arr = np.zeros(C, np.float32)
-        upd_rdep = np.zeros(C, np.float32)
-        upd_pdep = np.zeros(C, np.float32)
-        freed: list = []            # rows released by this chunk's deps
-        freed_seqs: list = []
-        fill = 0                    # events in the open chunk
-        nupd = 0                    # pool updates in the open chunk
+        seq0 = 0                        # seq of the block's first record
+        op = _OpenChunk(C, d, 0)
 
         def cut(final: bool) -> EventChunk:
-            nonlocal fill, nupd
-            # freed rows padded to the fixed (C,) geometry too, so the
-            # placement harvest shares the chunk step's single jit trace
+            nonlocal op, free, used
+            none = np.empty(0, np.int64)
+            rows = np.concatenate([none, *op.freed])
             fr = np.full(C, POOL_SENTINEL, np.int32)
-            fr[:len(freed)] = freed
+            fr[:len(rows)] = rows
             fseq = np.full(C, -1, np.int64)
-            fseq[:len(freed_seqs)] = freed_seqs
+            fseq[:len(rows)] = np.concatenate([none, *op.freed_seqs])
             chunk = EventChunk(
-                ev_t.copy(), ev_k.copy(), ev_i.copy(), fill,
-                upd_idx.copy(), upd_size.copy(), upd_arr.copy(),
-                upd_rdep.copy(), upd_pdep.copy(),
-                (ev_x.copy(),) if rcp else (),
-                fr, fseq, self.item_rows, final)
+                op.times, op.kinds, op.items, op.fill, op.upd_idx,
+                op.upd_size, op.upd_arrival, op.upd_rdep, op.upd_pdep,
+                (op.extra,) if rcp else (), fr, fseq, self.item_rows, final)
             # rows freed by this chunk become allocatable from the next
             # chunk on - never inside it (the pool scatter is chunk-start)
-            for r in freed:
-                heapq.heappush(free, int(r))
-            freed.clear()
-            freed_seqs.clear()
-            ev_t[:] = 0.0
-            ev_k[:] = PAD_KIND
-            ev_i[:] = 0
-            ev_x[:] = xcount
-            upd_idx[:] = POOL_SENTINEL
-            upd_size[:] = 0.0
-            upd_arr[:] = upd_rdep[:] = upd_pdep[:] = 0.0
-            fill = nupd = 0
+            if not self.identity:
+                free = np.sort(np.concatenate((free[used:], rows)))
+                used = 0
+            op = _OpenChunk(C, d, op.extra[-1])
             return chunk
 
-        def put(t: float, kind: int, row: int) -> Optional[EventChunk]:
-            nonlocal fill, xcount
-            ev_t[fill] = np.float32(t)
-            ev_k[fill] = kind
-            ev_i[fill] = row
-            ev_x[fill] = xcount
-            fill += 1
-            return cut(False) if fill == C else None
-
-        def alloc(seq: int) -> int:
-            nonlocal next_fresh
-            if not self.identity and free:
-                return heapq.heappop(free)
-            if next_fresh >= self.item_rows:
+        def alloc(seqs: np.ndarray) -> np.ndarray:
+            """Rows of a slice's arrivals, in arrival order."""
+            nonlocal used, next_fresh
+            if self.identity:
+                return seqs
+            take = min(len(seqs), len(free) - used)
+            fresh = len(seqs) - take
+            if next_fresh + fresh > self.item_rows:
                 if not self.grow:
+                    seq = seqs[take + self.item_rows - next_fresh]
                     raise RuntimeError(
                         f"item-row pool exhausted ({self.item_rows} rows) "
                         f"at request #{seq} with grow=False; pass a larger "
                         "item_rows or grow=True")
-                self.item_rows *= 2
-            row = next_fresh
-            next_fresh += 1
-            return row
+                while next_fresh + fresh > self.item_rows:
+                    self.item_rows *= 2
+                self._row_seq = np.concatenate((self._row_seq, np.full(
+                    self.item_rows - len(self._row_seq), -1, np.int64)))
+            rows = np.concatenate((free[used:used + take], np.arange(
+                next_fresh, next_fresh + fresh, dtype=np.int64)))
+            used += take
+            next_fresh += fresh
+            return rows
 
-        for size, arr, rdep, pdep in self.source.records():
-            if arr < last_arr:
-                raise ValueError(
-                    f"stream not arrival-sorted: {arr} after {last_arr}")
-            assert rdep > arr, f"departure {rdep} <= arrival {arr}"
-            last_arr = arr
-            # every departure at or before this arrival's time goes first
-            # (equal times: departures precede arrivals, by item seq)
-            while heap and heap[0][0] <= arr:
-                dt, dseq, drow = heapq.heappop(heap)
-                freed.append(drow)
-                freed_seqs.append(dseq)
-                del self._row_seq[drow]
-                out = put(dt, DEPARTURE_KIND, drow)
-                if out is not None:
-                    yield out
-            seq = self._seq_count
-            self._seq_count += 1
-            row = seq if self.identity else alloc(seq)
+        def fill(times, kinds, seqs, rows, extra, block):
+            """Write merged events into chunks, cutting each one full.
+            ``block`` (first seq, its rows, sizes, arrivals, rdeps, pdeps
+            as float32) resolves arrivals and in-block departures; None
+            for a stretch of pending departures."""
+            pos, E = 0, len(times)
+            while pos < E:
+                end = min(pos + C - op.fill, E)
+                kd, sq, rw = kinds[pos:end], seqs[pos:end], rows[pos:end]
+                dep = kd == DEPARTURE_KIND
+                if block is not None:
+                    s0, brows, sizes, arr, rdep, pdep = block
+                    arrived = ~dep
+                    aseq = sq[arrived]
+                    if len(aseq):
+                        ai = aseq - s0
+                        ar = alloc(aseq)
+                        brows[ai] = ar
+                        rw[arrived] = ar
+                        self._row_seq[ar] = aseq
+                        self._seq_count += len(ai)
+                        u0 = op.nupd
+                        u1 = op.nupd = u0 + len(ai)
+                        op.upd_idx[u0:u1] = ar
+                        op.upd_size[u0:u1] = sizes[ai]
+                        op.upd_arrival[u0:u1] = arr[ai]
+                        op.upd_rdep[u0:u1] = rdep[ai]
+                        op.upd_pdep[u0:u1] = pdep[ai]
+                    late = dep & (sq >= s0)     # arrived in this block
+                    rw[late] = brows[sq[late] - s0]
+                gone = rw[dep]
+                op.freed.append(gone)
+                op.freed_seqs.append(sq[dep])
+                self._row_seq[gone] = -1
+                f0 = op.fill
+                f1 = op.fill = f0 + end - pos
+                op.times[f0:f1] = times[pos:end]
+                op.kinds[f0:f1] = kd
+                op.items[f0:f1] = rw
+                if rcp:
+                    op.extra[f0:f1] = extra[pos:end]
+                pos = end
+                if f1 == C:
+                    yield cut(False)
+
+        while True:
+            recs = list(itertools.islice(records, B))
+            self.records_pulled += len(recs)
+            if not recs:
+                break
+            n = len(recs)
+            sizes, arr, rdep, pdep = _columns(recs, d)
+            _check_block(arr, rdep, last_arr)
+            last_arr = arr[-1]
+            seqs = np.arange(seq0, seq0 + n, dtype=np.int64)
+            # every departure at or before the block's last arrival is
+            # merged now (equal times: departures first, by item seq)
+            k = int(np.searchsorted(pend_t, last_arr, side="right"))
+            now = rdep <= last_arr
+            ndep = k + int(np.count_nonzero(now))
+            t = np.concatenate((pend_t[:k], rdep[now], arr))
+            sq = np.concatenate((pend_seq[:k], seqs[now], seqs))
+            arrival = np.zeros(ndep + n, bool)
+            arrival[ndep:] = True
+            order = np.lexsort((sq, arrival, t))
+            t, sq, arrival = t[order], sq[order], arrival[order]
+            rows = np.concatenate((pend_row[:k],
+                                   np.zeros(ndep - k + n, np.int64)))[order]
+            kinds = np.where(arrival, ARRIVAL_KIND,
+                             DEPARTURE_KIND).astype(np.int32)
+            extra = None
             if rcp:
                 # host twin of the device category: float32 duration
                 # arithmetic end to end, frexp-exact class boundaries
-                pdur = np.float32(pdep) - np.float32(arr)
-                cat = int(np.clip(geo_class(max(pdur, np.float32(0.0))),
-                                  0, KCAT - 1))
-                if not seen_cats[cat]:
-                    seen_cats[cat] = True
-                    xcount += 1
-            self._row_seq[row] = seq
-            upd_idx[nupd] = row
-            upd_size[nupd] = np.asarray(size, np.float32)[:d]
-            upd_arr[nupd] = np.float32(arr)
-            upd_rdep[nupd] = np.float32(rdep)
-            upd_pdep[nupd] = np.float32(pdep)
-            nupd += 1
-            heapq.heappush(heap, (float(rdep), seq, row))
-            out = put(arr, ARRIVAL_KIND, row)
-            if out is not None:
-                yield out
-        while heap:                 # drain the tail departures
-            dt, dseq, drow = heapq.heappop(heap)
-            freed.append(drow)
-            freed_seqs.append(dseq)
-            del self._row_seq[drow]
-            out = put(dt, DEPARTURE_KIND, drow)
-            if out is not None:
-                yield out
-        self._done = True
+                pdur = pdep.astype(np.float32) - arr.astype(np.float32)
+                cat = np.clip(geo_class(np.maximum(pdur, np.float32(0.0))),
+                              0, KCAT - 1)
+                cats, first = np.unique(cat, return_index=True)
+                new = ~seen_cats[cats]
+                seen_cats[cats[new]] = True
+                step = np.zeros(n, np.int32)
+                step[first[new]] = 1
+                inc = np.zeros(len(t), np.int32)
+                inc[arrival] = step[sq[arrival] - seq0]
+                extra = xcount + np.cumsum(inc, dtype=np.int32)
+                xcount = int(extra[-1])
+            brows = np.zeros(n, np.int64)
+            yield from fill(t.astype(np.float32), kinds, sq, rows, extra,
+                            (seq0, brows, sizes, arr.astype(np.float32),
+                             rdep.astype(np.float32),
+                             pdep.astype(np.float32)))
+            later = ~now
+            pt = np.concatenate((pend_t[k:], rdep[later]))
+            ps = np.concatenate((pend_seq[k:], seqs[later]))
+            pr = np.concatenate((pend_row[k:], brows[later]))
+            order = np.lexsort((ps, pt))
+            pend_t, pend_seq, pend_row = pt[order], ps[order], pr[order]
+            seq0 += n
+        # drain the tail departures
+        yield from fill(pend_t.astype(np.float32),
+                        np.full(len(pend_t), DEPARTURE_KIND, np.int32),
+                        pend_seq, pend_row,
+                        np.full(len(pend_t), xcount, np.int32), None)
         yield cut(True)
 
     # ----------------------------------------------------------- queries
@@ -321,7 +423,8 @@ class ChunkedWorkload:
     def live_rows(self):
         """{pool row: global item seq} still alive (empty after a full
         drain; non-empty only if iteration stopped early)."""
-        return dict(self._row_seq)
+        rows = np.flatnonzero(self._row_seq >= 0)
+        return dict(zip(rows.tolist(), self._row_seq[rows].tolist()))
 
 
 def synthetic_source(n_items: int, d: int = 4, seed: int = 0,

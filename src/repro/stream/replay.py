@@ -25,8 +25,9 @@ against the prefetching one.
 
 Recorded spans mark the staging boundaries: ``stream.init`` (builder,
 carry and pool of a pass; ``loads_bytes`` is the carried slot loads'
-size, which names the layout), ``stream.build`` (one chunk's heap merge and
-build), ``stream.put`` (its transfer), ``stream.step`` (its dispatch) and
+size, which names the layout), ``stream.build`` (one chunk's merge and
+build; ``records`` is how many source records it pulled, a whole block or
+none), ``stream.put`` (its transfer), ``stream.step`` (its dispatch) and
 ``stream.fence`` (waiting on the device); ``stream.replay`` carries the
 events and chunks replayed and the ``block_events`` of the last pass (0:
 per-event).  One span per chunk, never one per event.  The counter
@@ -200,8 +201,9 @@ def _replay_once(source, policy, *, chunk_events, item_rows, max_bins,
 
     def build():
         with obs.span("stream.build") as sp:
+            pulled = wl.records_pulled
             ch = next(gen)
-            sp.set(events=ch.n_events)
+            sp.set(events=ch.n_events, records=wl.records_pulled - pulled)
         return ch
 
     resumed_chunks = 0

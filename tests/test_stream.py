@@ -8,6 +8,7 @@ policy family, across chunk boundaries that land on MIGRATE events and on
 overflow escalations, through pool growth, checkpoint/resume and the
 multi-process sweep launcher's store merge.
 """
+import dataclasses
 import os
 import subprocess
 import sys
@@ -18,7 +19,7 @@ import pytest
 
 from repro import obs
 from repro.api.workload import Setting, stream_source, synthetic
-from repro.core import jaxsim
+from repro.core import Instance, jaxsim
 from repro.core.jaxsim import _replay_batch, simulate
 from repro.data.traces import _one_instance, load_azure_csv
 from repro.kernels import fitscore
@@ -28,7 +29,9 @@ from repro.resilience.checkpoint import StreamCheckpointer
 from repro.stream import (ChunkedWorkload, CsvSource, InstanceSource,
                           chunk_instance_events, replay_chunked_events,
                           replay_stream, synthetic_source)
+from repro.stream.events import RECORD_BLOCK, EventChunk
 from repro.sweep import SuiteSpec, SweepSpec, SweepStore, run_sweep
+from stream_ref import RefChunkedWorkload
 
 FIXTURE = os.path.join(os.path.dirname(__file__), "fixtures",
                        "azure_packing2020")
@@ -322,6 +325,9 @@ def test_recorded_spans_per_chunk(prefetch):
     builds = [e["args"]["events"] for e in evs
               if e["name"] == "stream.build"]
     assert sum(builds) == res.n_events
+    pulled = [e["args"]["records"] for e in evs
+              if e["name"] == "stream.build"]
+    assert sum(pulled) == inst.n_items
     rep, = [e for e in evs if e["name"] == "stream.replay"]
     assert rep["args"]["events"] == res.n_events
     assert rep["args"]["chunks"] == res.n_chunks
@@ -370,6 +376,160 @@ def test_chunk_builder_validates_order_and_pool():
     with pytest.raises(RuntimeError, match="pool exhausted"):
         list(ChunkedWorkload(src, "first_fit", chunk_events=16,
                              item_rows=2, grow=False).chunks())
+
+
+# --------------------------------------- block builder vs scalar reference
+
+# one policy per builder path: score, RCP (the ev_extra count), Hybrid
+# (identity mode)
+BUILDER_POLICIES = ("first_fit", "best_fit_linf", "rcp", "hybrid")
+
+
+def _noisy_source(seed=3, n=120):
+    """A stream whose predicted departures differ from the real ones."""
+    inst = _stream_instance(seed=seed, n=n)
+    rng = np.random.default_rng(seed)
+    return InstanceSource(inst, inst.durations *
+                          rng.lognormal(0.0, 1.0, inst.n_items))
+
+
+def _tied_source(seed=0, n=90):
+    """Whole-second times on a short grid: equal arrivals, departures at
+    other items' arrival times, equal-time departures, and items that
+    depart inside their arrival's chunk."""
+    rng = np.random.default_rng(seed)
+    arr = np.sort(rng.integers(0, 40, n)).astype(np.float64)
+    dep = arr + rng.integers(1, 6, n)
+    sizes = rng.integers(1, 9, (n, 4)) / 8.0
+    return InstanceSource(Instance(sizes, arr, dep, f"ties{seed}"))
+
+
+class _CutSource(InstanceSource):
+    """An in-memory source stopped after ``cut`` requests, counting what
+    it gave (as the benchmark's traced stream is)."""
+
+    def __init__(self, inst, cut):
+        super().__init__(inst)
+        self.cut, self.given = cut, 0
+
+    def records(self):
+        for rec in super().records():
+            if self.given == self.cut:
+                return
+            self.given += 1
+            yield rec
+
+
+def _assert_same_chunks(make_source, policy, record_block, **kw):
+    """The block builder yields the reference's chunks, field for field,
+    and ends in the same pool state."""
+    ref = RefChunkedWorkload(make_source(), policy, **kw)
+    new = ChunkedWorkload(make_source(), policy, **kw)
+    new.record_block = record_block
+    want, got = list(ref.chunks()), list(new.chunks())
+    assert len(got) == len(want)
+    for j, (a, b) in enumerate(zip(want, got)):
+        for f in dataclasses.fields(EventChunk):
+            x, y = getattr(a, f.name), getattr(b, f.name)
+            if f.name == "extras":
+                assert len(x) == len(y), (j, f.name)
+                for u, v in zip(x, y):
+                    assert u.dtype == v.dtype and np.array_equal(u, v), \
+                        (j, f.name)
+            elif isinstance(x, np.ndarray):
+                assert (x.dtype, x.shape) == (y.dtype, y.shape), (j, f.name)
+                assert np.array_equal(x, y), (j, f.name)
+            else:
+                assert x == y, (j, f.name)
+    assert (new.n_items, new.item_rows) == (ref.n_items, ref.item_rows)
+    assert new.live_rows() == ref.live_rows()
+    return got
+
+
+@pytest.mark.parametrize("record_block", (37, RECORD_BLOCK))
+@pytest.mark.parametrize("chunk_events", (1, 16, 2048))
+@pytest.mark.parametrize("policy", BUILDER_POLICIES)
+def test_block_builder_equals_reference(policy, chunk_events, record_block):
+    """Chunk for chunk equal to the scalar heap-merge builder, with a pool
+    that starts at 3 rows and grows mid-chunk (recycling beside it)."""
+    got = _assert_same_chunks(_noisy_source, policy, record_block,
+                              chunk_events=chunk_events, item_rows=3)
+    if policy != "hybrid" and chunk_events < 2048:
+        assert got[-1].item_rows > 3
+
+
+@pytest.mark.parametrize("chunk_events", (1, 16, 2048))
+@pytest.mark.parametrize("policy", BUILDER_POLICIES)
+def test_block_builder_equal_time_ties(policy, chunk_events):
+    """Departure == arrival times, equal-time departures ordered by item
+    seq, equal arrivals in source order, short-lived items departing in
+    their arrival's chunk: the same chunks as the reference."""
+    src = _tied_source()
+    dep, arr = src.inst.departures, src.inst.arrivals
+    assert np.isin(dep, arr).any() and len(np.unique(dep)) < len(dep)
+    _assert_same_chunks(_tied_source, policy, 37,
+                        chunk_events=chunk_events, item_rows=4)
+
+
+@pytest.mark.parametrize("policy", BUILDER_POLICIES)
+def test_block_builder_cut_source(policy):
+    """A source cut early (the benchmark's traced stream): the pending
+    departures drain and the chunks equal the reference's."""
+    inst = _stream_instance(seed=6, n=120)
+    _assert_same_chunks(lambda: _CutSource(inst, 50), policy, 37,
+                        chunk_events=16, item_rows=8)
+
+
+def test_block_builder_exhaustion_matches_reference():
+    """grow=False: the same chunks before the same RuntimeError."""
+    def drain(wl):
+        out = []
+        with pytest.raises(RuntimeError, match="pool exhausted") as e:
+            for ch in wl.chunks():
+                out.append(ch.n_events)
+        return out, str(e.value)
+    src = _stream_instance(seed=2, n=60)
+    new = ChunkedWorkload(InstanceSource(src), "first_fit", chunk_events=8,
+                          item_rows=5, grow=False)
+    new.record_block = 37
+    assert drain(new) == drain(RefChunkedWorkload(
+        InstanceSource(src), "first_fit", chunk_events=8, item_rows=5,
+        grow=False))
+
+
+def test_block_builder_rejects_departure_at_arrival():
+    """A record whose departure is not after its arrival is refused, also
+    past the first block."""
+    src = InstanceSource(_stream_instance(seed=2, n=60))
+
+    class Instant:
+        def meta(self):
+            return src.meta()
+
+        def records(self):
+            for j, (size, arr, dep, pdep) in enumerate(src.records()):
+                yield size, arr, arr if j == 45 else dep, pdep
+
+    wl = ChunkedWorkload(Instant(), "first_fit", chunk_events=16,
+                         item_rows=8)
+    wl.record_block = 37
+    with pytest.raises(ValueError, match="<= arrival"):
+        list(wl.chunks())
+
+
+def test_block_builder_is_lazy_and_bounded():
+    """The first chunk is yielded after at most one block of records, and
+    the builder pulls every record exactly once."""
+    inst = _stream_instance(seed=8, n=200)
+    src = _CutSource(inst, inst.n_items)
+    wl = ChunkedWorkload(src, "first_fit", chunk_events=16, item_rows=8)
+    wl.record_block = 37
+    gen = wl.chunks()
+    next(gen)
+    assert src.given <= 37
+    for _ in gen:
+        pass
+    assert src.given == wl.records_pulled == wl.n_items == inst.n_items
 
 
 def test_chunk_instance_events_padding():
